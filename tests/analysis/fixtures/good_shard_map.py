@@ -2,7 +2,7 @@
 
 Mentioning shard_map in a docstring or comment is fine: the rule is an
 AST pass, not a grep. Everything executable goes through
-``shard_map_compat`` (the check_rep/check_vma rename shim).
+``shard_map_compat`` (the repo's one shard_map call site).
 """
 
 from tpu_gossip.dist._compat import shard_map_compat

@@ -26,8 +26,10 @@ def build(mesh):
         # shard-varying predicate: each shard reads its own slice
         return jax.lax.cond(
             x[0] > 0.0,
-            lambda v: jax.lax.psum(v, AXIS),  # arm 1 rendezvouses...
-            lambda v: v,                      # ...arm 0 never does
+            # arm 1 rendezvouses (re-typed varying so the arms trace
+            # under check_vma)...
+            lambda v: jax.lax.pcast(jax.lax.psum(v, AXIS), AXIS, to="varying"),
+            lambda v: v,  # ...arm 0 never does
             x,
         )
 

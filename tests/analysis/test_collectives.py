@@ -171,7 +171,9 @@ def test_uniform_pred_cond_may_diverge():
         total = jax.lax.psum(jnp.sum(x), AXIS)  # mesh-agreed scalar
         return jax.lax.cond(
             total > 0.0,
-            lambda v: jax.lax.psum(v, AXIS),
+            lambda v: jax.lax.pcast(
+                jax.lax.psum(v, AXIS), AXIS, to="varying"
+            ),
             lambda v: v,
             x,
         )

@@ -78,7 +78,7 @@ def test_donation_credit_collapses_pjit_footprint():
 
     closed = jax.make_jaxpr(lambda x: g(x))(jnp.zeros((1024,), jnp.float32))
     [eqn] = closed.jaxpr.eqns
-    assert eqn.primitive.name == "pjit" and any(
+    assert eqn.primitive.name == "jit" and any(
         eqn.params["donated_invars"]
     )
     peak, _ = _analyze(closed.jaxpr, {})

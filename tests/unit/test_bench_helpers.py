@@ -86,3 +86,14 @@ def test_compact_carries_lint_clean():
     }
     compact = bench._compact(out)
     assert compact["lint_clean"] is True
+
+
+@pytest.mark.parametrize("kind, peak", [("TPU v5 lite", 819.0), ("cpu", None)])
+def test_hbm_peak_is_keyed_by_device_kind(kind, peak):
+    """The published peak is looked up by device_kind; a kind the table
+    does not list is an error, never a v5e default."""
+    if peak is None:
+        with pytest.raises(ValueError, match="no published HBM peak"):
+            bench.hbm_peak_gbps(kind)
+    else:
+        assert bench.hbm_peak_gbps(kind) == peak

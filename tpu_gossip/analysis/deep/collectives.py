@@ -75,19 +75,21 @@ RULE = "deep-collective-uniformity"
 LOCK_RULE = "deep-collective-lock-drift"
 DEFAULT_LOCK = "collectives.lock"
 
-# wire-moving collective primitives recorded into the program (psum
-# traces as psum2 on this jax, like reductions.py; the *2 spellings are
-# kept for both families)
+# wire-moving collective primitives recorded into the program. Under
+# check_vma a psum of a varying value traces as ``psum_invariant``; it is
+# recorded under its wire name (``_PRIM_NAMES``), like the *2 spellings
 _RECORDED = frozenset({
-    "psum", "psum2", "pmax", "pmax2", "pmin", "pmin2",
+    "psum", "psum2", "psum_invariant", "pmax", "pmax2", "pmin", "pmin2",
     "all_to_all", "all_gather", "ppermute", "pshuffle", "reduce_scatter",
 })
+_PRIM_NAMES = {"psum_invariant": "psum"}
 
 # collectives whose OUTPUT is bit-identical on every shard of the named
 # axis (reductions replicate their result; all_gather hands every shard
 # the same concatenation)
 _UNIFORM_OUT = frozenset({
-    "psum", "psum2", "pmax", "pmax2", "pmin", "pmin2", "all_gather",
+    "psum", "psum2", "psum_invariant", "pmax", "pmax2", "pmin", "pmin2",
+    "all_gather",
 })
 
 # check_rep replication bookkeeping: physically a no-op (no wire), and
@@ -205,8 +207,9 @@ class _EntryWalk:
         per_axis = tuple(
             (ax, per_shard * int(axis_sizes.get(ax, 1))) for ax in axes
         )
+        prim = eqn.primitive.name
         sink.append(CollectiveOp(
-            prim=eqn.primitive.name, axes=axes, shape=shape, dtype=dtype,
+            prim=_PRIM_NAMES.get(prim, prim), axes=axes, shape=shape, dtype=dtype,
             path=path, bytes_per_shard=per_shard, per_axis=per_axis,
         ))
 

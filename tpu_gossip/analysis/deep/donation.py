@@ -80,7 +80,7 @@ def donation_jaxpr_findings(traced) -> list[Finding]:
         state_leaves = set(te.jaxpr.jaxpr.invars)
         pjits = [
             e for e in te.jaxpr.jaxpr.eqns
-            if e.primitive.name == "pjit"
+            if e.primitive.name == "jit"
             and e.params.get("name") == ep.jit_name
         ]
         if not pjits:

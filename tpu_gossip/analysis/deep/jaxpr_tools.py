@@ -84,13 +84,21 @@ def src_of(eqn) -> SrcFrame | None:
     try:
         from jax._src import source_info_util as siu
 
-        frames = list(siu.user_frames(eqn.source_info))
+        frames = list(siu.user_frames(eqn.source_info.traceback))
     except Exception:  # noqa: BLE001 — source info is best-effort
         return None
     for fr in frames:
         f = fr.file_name.replace("\\", "/")
         if "/tpu_gossip/" in f and "/tpu_gossip/analysis/" not in f:
-            return SrcFrame(_rel(f), fr.function_name, fr.start_line)
+            return SrcFrame(_rel(f), _bare(fr.function_name), fr.start_line)
     for fr in frames:
-        return SrcFrame(_rel(fr.file_name), fr.function_name, fr.start_line)
+        return SrcFrame(
+            _rel(fr.file_name), _bare(fr.function_name), fr.start_line
+        )
     return None
+
+
+def _bare(function_name: str) -> str:
+    """The function's own name: jax reports the qualified name
+    (``_exchange.<locals>.ex``), the allowlists key on ``ex``."""
+    return function_name.rsplit(".", 1)[-1]

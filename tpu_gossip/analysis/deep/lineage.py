@@ -269,7 +269,7 @@ class _Analysis:
         if prim == "random_fold_in":
             self._fold(eqn, env, consts)
             return
-        if prim in ("pjit", "closed_call", "custom_jvp_call",
+        if prim in ("jit", "closed_call", "custom_jvp_call",
                     "custom_vjp_call", "remat", "checkpoint"):
             self._call(eqn, env, consts, inside_sm)
             return

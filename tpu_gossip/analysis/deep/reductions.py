@@ -48,12 +48,12 @@ REDUCTION_ALLOWLIST: Dict[Tuple[str, str], str] = {
     ),
 }
 
-# "psum2" is the post-2024 spelling of the psum primitive (jax renamed it
-# under shard_map's replication-rule rework); both must match or the pass
+# "psum2" and "psum_invariant" (a psum of a varying value under check_vma)
+# are other spellings of the psum primitive; all must match or the pass
 # goes silently blind on the collective it most exists to catch. pmax/pmin
 # are NOT here: max/min are associative and commutative exactly, so their
 # bracketing cannot depend on layout (the docstring's order-exact carve-out)
-_COLLECTIVES = ("psum", "psum2")
+_COLLECTIVES = ("psum", "psum2", "psum_invariant")
 _GLOBAL_REDUCES = ("reduce_sum", "reduce_prod", "dot_general")
 
 

@@ -80,7 +80,11 @@ def divergent_collective_entry():
         pred = x[0] > 0.0
         return jax.lax.cond(
             pred,
-            lambda v: jax.lax.psum(v, AXIS),
+            # the psum's replicated result re-typed as varying, so the
+            # arms trace under check_vma; the collective stays in one arm
+            lambda v: jax.lax.pcast(
+                jax.lax.psum(v, AXIS), AXIS, to="varying"
+            ),
             lambda v: v,
             x,
         )
@@ -116,7 +120,11 @@ def divergent_dcn_collective_entry():
         pred = x[0] > 0.0
         return jax.lax.cond(
             pred,
-            lambda v: jax.lax.psum(v, HOST_AXIS),
+            # the psum's replicated result re-typed as varying, so the
+            # arms trace under check_vma; the collective stays in one arm
+            lambda v: jax.lax.pcast(
+                jax.lax.psum(v, HOST_AXIS), HOST_AXIS, to="varying"
+            ),
             lambda v: v,
             x,
         )

@@ -107,7 +107,7 @@ def _carry_credit(eqn, sizes) -> int:
     loop-carry aliasing)."""
     prim = eqn.primitive.name
     invars = list(eqn.invars)
-    if prim == "pjit":
+    if prim == "jit":
         donated = eqn.params.get("donated_invars")
         if donated:
             return sum(
@@ -278,7 +278,7 @@ def _donation_footprint(te, jit_name: str, state_bytes: int):
     from jax._src import core
 
     for eqn in te.jaxpr.jaxpr.eqns:
-        if eqn.primitive.name != "pjit" or eqn.params.get("name") != jit_name:
+        if eqn.primitive.name != "jit" or eqn.params.get("name") != jit_name:
             continue
         donated = eqn.params.get("donated_invars") or ()
         credit = sum(
@@ -302,7 +302,7 @@ def _clone_eqns(te):
         try:
             from jax._src import source_info_util as siu
 
-            frames = list(siu.user_frames(eqn.source_info))
+            frames = list(siu.user_frames(eqn.source_info.traceback))
         except Exception:  # noqa: BLE001 — source info is best-effort
             frames = []
         if any(fr.function_name == "clone_state" for fr in frames):

@@ -40,12 +40,13 @@ _WIRE_ENTRIES = {
     "dist[matching,2d]": "matching",
 }
 
-# psum2/pmax2/pmin2 are the check_rep-era spellings jax traces for the
-# same wire ops — censused under their base name so the report columns
-# stay stable across jax versions
+# psum2/pmax2/pmin2 and psum_invariant (a psum of a varying value under
+# check_vma) are other spellings jax traces for the same wire ops —
+# censused under their base name so the report columns stay stable
 _COLLECTIVES = ("all_to_all", "psum", "pmax", "pmin", "ppermute",
                 "all_gather")
-_PRIM_ALIASES = {"psum2": "psum", "pmax2": "pmax", "pmin2": "pmin"}
+_PRIM_ALIASES = {"psum2": "psum", "pmax2": "pmax", "pmin2": "pmin",
+                 "psum_invariant": "psum"}
 
 
 def _aval_words(aval) -> int:

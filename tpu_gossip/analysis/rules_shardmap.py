@@ -1,11 +1,10 @@
 """raw-shard-map: all shard_map use routes through dist/_compat.py.
 
-The invariant: jax renamed ``jax.experimental.shard_map.shard_map``
-(kwarg ``check_rep``) to ``jax.shard_map`` (kwarg ``check_vma``), and the
-container and the TPU bench env straddle the rename — raw references broke
-all 23 dist tests once (CHANGES.md PR 1). ``shard_map_compat``
-(tpu_gossip/dist/_compat.py) is the one place allowed to touch either
-spelling; everything else imports the shim. Docstrings and comments are
+The invariant: ``shard_map_compat`` (tpu_gossip/dist/_compat.py) is the
+one place allowed to touch ``jax.shard_map`` or the retired
+``jax.experimental.shard_map`` spelling; everything else imports it, so a
+jax API change is absorbed in one file (the ``check_rep``→``check_vma``
+rename once broke all 23 dist tests, CHANGES.md PR 1). Docstrings and comments are
 naturally exempt (this is an AST pass, not a grep).
 """
 
@@ -20,8 +19,8 @@ __all__ = ["check_raw_shard_map"]
 
 _ALLOWED_FILES = ("tpu_gossip/dist/_compat.py",)
 _HINT = (
-    "route through tpu_gossip.dist._compat.shard_map_compat (the "
-    "check_rep/check_vma rename shim)"
+    "route through tpu_gossip.dist._compat.shard_map_compat (the repo's "
+    "one shard_map call site)"
 )
 
 

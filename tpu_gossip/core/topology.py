@@ -153,23 +153,20 @@ def preferential_attachment(
     a power-law degree distribution with gamma ≈ 3.
 
     Degree-proportional sampling uses the repeated-endpoints list: a uniform
-    index into the list of all edge endpoints selects nodes ∝ degree. Prefers
+    index into the list of all edge endpoints selects nodes ∝ degree. Runs
     the C++ generator in ``tpu_gossip.native`` (growth is inherently
-    sequential, so the Python loop is the slow path).
+    sequential, so the Python loop is the slow path), which builds itself
+    from the checkout and raises if it cannot. ``use_native=False`` runs the
+    numpy loop instead: the same law, a different draw of the graph.
     """
     if rng is None:
         rng = np.random.default_rng(0)
     if n < m + 1:
         raise ValueError(f"need n > m, got n={n} m={m}")
     if use_native:
-        try:
-            from tpu_gossip.native import pa_edges_native
+        from tpu_gossip.native import pa_edges_native
 
-            out = pa_edges_native(n, m, seed=int(rng.integers(2**31 - 1)))
-            if out is not None:
-                return out
-        except ImportError:
-            pass
+        return pa_edges_native(n, m, seed=int(rng.integers(2**31 - 1)))
 
     # seed clique over the first m+1 nodes
     seed_nodes = np.arange(m + 1)

@@ -1,4 +1,5 @@
-"""jax API compatibility shims shared by both dist engines."""
+"""The repo's one ``shard_map`` call site (the ``raw-shard-map`` lint rule
+routes every engine through it)."""
 
 from __future__ import annotations
 
@@ -8,19 +9,8 @@ __all__ = ["shard_map_compat"]
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across jax versions: the bound API landed as
-    ``jax.experimental.shard_map.shard_map`` (kwarg ``check_rep``) and was
-    promoted to ``jax.shard_map`` (kwarg ``check_vma``); the container and
-    the TPU bench env straddle the rename, so both engines route through
-    this one shim."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    """``jax.shard_map`` with the repo's keyword set."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )

@@ -48,6 +48,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpu_gossip.kernels.backend import interpret_default
+
 __all__ = [
     "StaircasePlan",
     "build_staircase_plan",
@@ -461,7 +463,7 @@ def _launch(
     scalar-prefetch index maps mix shard-varying tables with the loop
     index, which JAX's varying-axes tracker cannot type)."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     rows = plan.rows
     billed = bill is not None
     mm = m + 1 if billed else m
@@ -528,7 +530,7 @@ def stream_segment_or(
     within the tile's output block, or -1 for positions outside the tile's
     segment. Returns (n, m) bool."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     vals2d = vals_flat.reshape(-1, 128)
     edge_spec = pl.BlockSpec((8, 128), lambda t, tb, fv, wi: (t, 0))
     vals_spec = pl.BlockSpec((8, 128), lambda t, tb, fv, wi: (wi[t], 0))

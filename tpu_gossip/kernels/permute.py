@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from tpu_gossip.kernels.backend import interpret_default
+
 __all__ = [
     "BLOCK_ROWS",
     "lane_shuffle",
@@ -54,9 +56,13 @@ BLOCK_ROWS = 2048  # rows per Pallas grid step; R must be a multiple
 
 
 def _shuffle_kernel(x_ref, idx_ref, o_ref):
-    o_ref[:] = jnp.take_along_axis(
-        x_ref[:], idx_ref[:].astype(jnp.int32), axis=1
+    # the chip's lane gather moves 32-bit data only: narrow payloads (the
+    # sharded engine's uint8 words) widen in VMEM and narrow on the store
+    x = x_ref[:]
+    out = jnp.take_along_axis(
+        x.astype(jnp.int32), idx_ref[:].astype(jnp.int32), axis=1  # graftlint: disable=mem-widening-cast -- per-block VMEM window: the stored words keep their dtype
     )
+    o_ref[:] = out.astype(o_ref.dtype)
 
 
 def _shuffle_call(x, idx, rows, interpret):
@@ -86,7 +92,7 @@ def lane_shuffle(
     permutation pipeline is built from.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     r = x.shape[0]
     if r % 8 != 0:
         raise ValueError(f"rows {r} not a multiple of 8")
@@ -254,7 +260,7 @@ def fold_planes(
     argument-count and compile-time walls as pad_deg grew).
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     if slot_off % 1024 or cstride % 1024:
         raise ValueError("fold_planes needs 1024-aligned slot_off/cstride")
     base = slot_off // 1024

@@ -52,8 +52,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from tpu_gossip.kernels.backend import interpret_default
 
 __all__ = [
     "TAIL_IMPLS",
@@ -195,6 +197,18 @@ def tail_fused(
     return new_seen, new_fwd, new_ir, new_rec
 
 
+def _round_scalar(rnd, dtype) -> jax.Array:
+    """The round cursor as the kernels' (1,) int32 SMEM operand,
+    pre-saturated at the plane's narrow ``dtype``: Mosaic extracts only
+    32-bit scalars, so the kernel narrows it after the read."""
+    from tpu_gossip.core.state import saturate_round
+
+    return (
+        saturate_round(jnp.asarray(rnd, jnp.int32), dtype)
+        .astype(jnp.int32).reshape(1)
+    )
+
+
 def _tail_kernel(
     forward_once: bool, sir: int, has_fresh: bool, has_expired: bool
 ):
@@ -218,7 +232,11 @@ def _tail_kernel(
         o_rec = next(it)
         o_fwd = next(it) if needs_fwd else None
 
-        rnd = rnd_ref[0, 0]
+        # the narrow plane computes in an int32 window (the chip's vector
+        # unit has no 16-bit compares) and narrows again on the store; rnd
+        # arrives pre-saturated at the plane's dtype, so no value changes
+        ir = ir_ref[...].astype(jnp.int32)  # graftlint: disable=mem-widening-cast -- per-block VMEM window: the stored plane stays int16
+        rnd = rnd_ref[0]
         seen = seen_ref[...]
         inc = inc_ref[...] & recp_ref[...]
         keep = None
@@ -232,21 +250,14 @@ def _tail_kernel(
             new_seen = new_seen & keep
         o_seen[...] = new_seen
 
-        ir = ir_ref[...]
-        # rnd arrives pre-saturated at the plane's narrow dtype; the SIR
-        # age arithmetic widens to int32 so the (-1)-sentinel lanes can't
-        # wrap at the cap edge
         new_ir = jnp.where((inc & ~seen) & (ir < 0), rnd, ir)
         rec = rec_ref[...]
         if sir > 0:
-            rec = rec | (
-                (new_ir >= 0)
-                & (rnd.astype(jnp.int32) - new_ir.astype(jnp.int32) >= sir)  # graftlint: disable=mem-widening-cast -- transient SIR age staging inside the kernel window: the stored plane stays int16; the subtraction widens so sentinel lanes cannot wrap
-            )
+            rec = rec | ((new_ir >= 0) & (rnd - new_ir >= sir))
         if keep is not None:
             new_ir = jnp.where(keep, new_ir, -1)
             rec = rec & keep
-        o_ir[...] = new_ir
+        o_ir[...] = new_ir.astype(o_ir.dtype)
         o_rec[...] = rec
 
         if o_fwd is not None:
@@ -286,7 +297,7 @@ def tail_pallas(
     replicated (1, M) operand every grid step reads.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_default()
     n, m = seen.shape
     has_fresh = fresh is not None
     has_expired = expired is not None
@@ -297,7 +308,7 @@ def tail_pallas(
     row_spec = pl.BlockSpec((blk, m), lambda i: (i, 0))
     one_spec = pl.BlockSpec((blk, 1), lambda i: (i, 0))
     col_spec = pl.BlockSpec((1, m), lambda i: (0, 0))
-    rnd_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    rnd_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     args = [seen, infected_round, recovered, incoming, receptive]
     in_specs = [row_spec] * 5
@@ -313,12 +324,7 @@ def tail_pallas(
     if has_expired:
         args.append(expired[None, :])
         in_specs.append(col_spec)
-    from tpu_gossip.core.state import saturate_round
-
-    args.append(
-        saturate_round(jnp.asarray(rnd, jnp.int32), infected_round.dtype)
-        .reshape(1, 1)
-    )
+    args.append(_round_scalar(rnd, infected_round.dtype))
     in_specs.append(rnd_spec)
 
     out_shape = [
@@ -346,17 +352,16 @@ def tail_pallas(
 
 def _decode_words(words, m):
     """Static-unrolled word->bool decode for INSIDE Pallas kernels (no
-    reshape games on the lane dim; M is small). Host-side code never uses
-    this — full-width decode routes through ``core.packed.unpack_bits``."""
-    cols = [
-        (words[:, j // 8] >> np.uint8(j % 8)) & np.uint8(1)
-        for j in range(m)
-    ]
+    reshape games on the lane dim; M is small). ``words`` is the int32
+    window of the uint8 words. Host-side code never uses this — full-width
+    decode routes through ``core.packed.unpack_bits``."""
+    cols = [(words[:, j // 8] >> (j % 8)) & 1 for j in range(m)]
     return jnp.stack(cols, axis=-1) != 0
 
 
 def _encode_words(bools, w):
-    """Static-unrolled bool->word encode for INSIDE Pallas kernels."""
+    """Static-unrolled bool->word encode for INSIDE Pallas kernels, into
+    int32 words (the kernel narrows them to uint8 on the store)."""
     m = bools.shape[-1]
     outs = []
     for g in range(w):
@@ -365,7 +370,7 @@ def _encode_words(bools, w):
             j = g * 8 + k
             if j >= m:
                 break
-            bit = bools[:, j].astype(jnp.uint8) << np.uint8(k)
+            bit = bools[:, j].astype(jnp.int32) << k
             acc = bit if acc is None else acc | bit
         outs.append(acc)
     return jnp.stack(outs, axis=-1)
@@ -374,7 +379,9 @@ def _encode_words(bools, w):
 def _tail_words_kernel(m, w, forward_once, sir, has_fresh, has_expired):
     """One grid step of the packed tail over a (block_rows,) row window:
     uint8 word planes ride (blk, W) blocks, the int16 ``infected_round``
-    plane rides (blk, M) blocks, in the same launch."""
+    plane rides (blk, M) blocks, in the same launch. Every narrow plane
+    computes in an int32 window (the chip's vector unit has no 8- or
+    16-bit shifts and compares) and narrows again on the store."""
     needs_fwd = forward_once or has_fresh or has_expired
 
     def kernel(*refs):
@@ -394,14 +401,18 @@ def _tail_words_kernel(m, w, forward_once, sir, has_fresh, has_expired):
         o_rec = next(it)
         o_fwd = next(it) if needs_fwd else None
 
-        rnd = rnd_ref[0, 0]
-        seen = seen_ref[...]
-        inc = inc_ref[...] & recp_ref[...]
+        def wide(ref):
+            return ref[...].astype(jnp.int32)  # graftlint: disable=mem-widening-cast -- per-block VMEM window: the stored planes stay uint8/int16
+
+        ir = wide(ir_ref)
+        rnd = rnd_ref[0]
+        seen = wide(seen_ref)
+        inc = wide(inc_ref) & wide(recp_ref)
         keep_w = None
         keep_rows = None
         if has_fresh:
             keep_rows = ~fresh_ref[...]  # (blk, 1) bool
-            keep_w = jnp.where(keep_rows, jnp.uint8(0xFF), jnp.uint8(0))
+            keep_w = jnp.where(keep_rows, 0xFF, 0)
         if has_expired:
             exp = exp_ref[...]  # (1, M) bool
             ec = _encode_words(~exp, w)  # conforming (1, W) keep words
@@ -409,17 +420,14 @@ def _tail_words_kernel(m, w, forward_once, sir, has_fresh, has_expired):
         new_seen = seen | inc
         if keep_w is not None:
             new_seen = new_seen & keep_w
-        o_seen[...] = new_seen
+        o_seen[...] = new_seen.astype(o_seen.dtype)
 
-        ir = ir_ref[...]
         newly = _decode_words(inc & ~seen, m)
         new_ir = jnp.where(newly & (ir < 0), rnd, ir)
-        rec = rec_ref[...]
+        rec = wide(rec_ref)
         if sir > 0:
             rec = rec | _encode_words(
-                (new_ir >= 0)
-                & (rnd.astype(jnp.int32) - new_ir.astype(jnp.int32) >= sir),  # graftlint: disable=mem-widening-cast -- transient SIR age staging inside the kernel window: the stored plane stays int16; the subtraction widens so sentinel lanes cannot wrap
-                w,
+                (new_ir >= 0) & (rnd - new_ir >= sir), w,
             )
         if has_fresh:
             new_ir = jnp.where(keep_rows, new_ir, -1)
@@ -427,16 +435,16 @@ def _tail_words_kernel(m, w, forward_once, sir, has_fresh, has_expired):
             new_ir = jnp.where(exp_ref[...], -1, new_ir)
         if keep_w is not None:
             rec = rec & keep_w
-        o_ir[...] = new_ir
-        o_rec[...] = rec
+        o_ir[...] = new_ir.astype(o_ir.dtype)
+        o_rec[...] = rec.astype(o_rec.dtype)
 
         if o_fwd is not None:
-            fwd = fwd_ref[...]
+            fwd = wide(fwd_ref)
             if forward_once:
-                fwd = fwd | tx_ref[...]
+                fwd = fwd | wide(tx_ref)
             if keep_w is not None:
                 fwd = fwd & keep_w
-            o_fwd[...] = fwd
+            o_fwd[...] = fwd.astype(o_fwd.dtype)
 
     return kernel
 
@@ -479,9 +487,7 @@ def round_tail_words(
 
     if pallas:
         if interpret is None:
-            interpret = jax.default_backend() == "cpu"
-        from tpu_gossip.core.state import saturate_round
-
+            interpret = interpret_default()
         n, w = seen_w.shape
         has_fresh = fresh is not None
         has_expired = expired is not None
@@ -492,7 +498,7 @@ def round_tail_words(
         wide_spec = pl.BlockSpec((blk, m), lambda i: (i, 0))
         one_spec = pl.BlockSpec((blk, 1), lambda i: (i, 0))
         col_spec = pl.BlockSpec((1, m), lambda i: (0, 0))
-        rnd_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
+        rnd_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
         args = [seen_w, infected_round, recovered_w, incoming_w, receptive_w]
         in_specs = [word_spec, wide_spec, word_spec, word_spec, word_spec]
@@ -508,10 +514,7 @@ def round_tail_words(
         if has_expired:
             args.append(expired[None, :])
             in_specs.append(col_spec)
-        args.append(
-            saturate_round(jnp.asarray(rnd, jnp.int32), infected_round.dtype)
-            .reshape(1, 1)
-        )
+        args.append(_round_scalar(rnd, infected_round.dtype))
         in_specs.append(rnd_spec)
 
         out_shape = [

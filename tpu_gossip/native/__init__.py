@@ -5,25 +5,61 @@ package exists because the TPU build moves graph *construction* to the host
 critical path at much larger N (1M-10M nodes), where the inherently
 sequential preferential-attachment loop is worth a C++ implementation.
 
-``pa_edges_native`` loads ``libtpugossip.so`` (built by ``build.sh`` /
-``make -C tpu_gossip/native``) via ctypes and returns preferential-attachment
-edges; returns None when the library is absent so callers fall back to numpy.
+``pa_edges_native`` loads ``libtpugossip.so`` via ctypes. The library is
+built from ``csrc/`` into this directory of the checkout the first time it
+is needed (or when the source is newer), with portable flags: the checkout
+may be copied to another machine. A library that cannot be built is an
+error, never a silent switch to the numpy generator, which draws a
+different graph.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import subprocess
 
 import numpy as np
 
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "libtpugossip.so")
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_LIB_PATH = os.path.join(_HERE, "libtpugossip.so")
+_SRC = os.path.join(_HERE, "csrc", "pa_edges.cc")
+# no -march=native: the library must run on whatever host loads the checkout
+CXXFLAGS = ("-O3", "-fPIC", "-std=c++17", "-Wall", "-Wextra", "-shared")
 _lib = None
+
+
+def build_library() -> str:
+    """Compile ``csrc/pa_edges.cc`` to ``libtpugossip.so``; returns its path.
+
+    Writes a per-process temporary next to the target and renames it into
+    place, so concurrent builders (test workers) never load a torn file.
+    Raises ``RuntimeError`` with the compiler's output if the build fails.
+    """
+    cxx = os.environ.get("CXX", "g++")
+    tmp = f"{_LIB_PATH[:-len('.so')]}.{os.getpid()}.tmp.so"  # git-ignored
+    try:
+        proc = subprocess.run(
+            [cxx, *CXXFLAGS, "-o", tmp, _SRC],
+            capture_output=True, text=True, timeout=300,
+        )
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise RuntimeError(f"building {_LIB_PATH} with {cxx}: {e}") from e
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"building {_LIB_PATH} failed (rc={proc.returncode}):\n"
+            f"{proc.stderr.strip()[-2000:]}"
+        )
+    os.replace(tmp, _LIB_PATH)
+    return _LIB_PATH
 
 
 def _load():
     global _lib
-    if _lib is None and os.path.exists(_LIB_PATH):
+    if _lib is None:
+        if (not os.path.exists(_LIB_PATH)
+                or os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)):
+            build_library()
         lib = ctypes.CDLL(_LIB_PATH)
         lib.pa_edges.argtypes = [
             ctypes.c_int64,  # n
@@ -37,11 +73,9 @@ def _load():
     return _lib
 
 
-def pa_edges_native(n: int, m: int, seed: int = 0) -> np.ndarray | None:
-    """C++ Barabási–Albert generator; (E,2) int64 edges or None if lib missing."""
+def pa_edges_native(n: int, m: int, seed: int = 0) -> np.ndarray:
+    """C++ Barabási–Albert generator; (E, 2) int64 edges."""
     lib = _load()
-    if lib is None:
-        return None
     cap = m * (m + 1) // 2 + (n - m - 1) * m + 16
     out = np.empty((cap, 2), dtype=np.int64)
     wrote = lib.pa_edges(
