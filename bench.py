@@ -987,68 +987,6 @@ def bench_churn_remat(dg, *, msg_slots: int = 16, reps: int = 3,
     }
 
 
-def bench_tail_ab(dg, plan=None, reps: int = 3, warm_rounds: int = 6):
-    """The --tail default decision, automated (ISSUE 10 satellite): the
-    composed round slope-timed per tail implementation on THIS platform,
-    so the next hardware bench run answers the open pallas-default
-    question without hand work.
-
-    The config turns every tail branch on (SIR + churn fresh masks ride
-    the producing selects). On a CPU container the pallas tail is
-    interpret-mode — functional-only, unmeasurable at scale — so the
-    A/B covers reference vs fused and records the caveat; on a TPU the
-    pallas row appears and the decision is the fastest composed round.
-    """
-    import jax
-
-    from tpu_gossip.core.state import SwarmConfig, clone_state, init_swarm
-    from tpu_gossip.sim.engine import simulate
-    from tpu_gossip.utils.profiling import profile_round_stages
-
-    on_cpu = jax.default_backend() == "cpu"
-    tails = ("reference", "fused") + (() if on_cpu else ("pallas",))
-    if on_cpu:
-        # the staircase delivery kernel interprets on CPU (functional-only,
-        # hours at 1M) — the tail A/B needs only a delivery to feed the
-        # tails, so the XLA path carries it here; on TPU the plan rides
-        plan = None
-    cfg = SwarmConfig(
-        n_peers=dg.n_pad, msg_slots=16, fanout=1, mode="push_pull",
-        sir_recover_rounds=8, churn_leave_prob=0.002, churn_join_prob=0.02,
-        rewire_slots=2, rewire_compact_cap=65536,
-    )
-    st = init_swarm(
-        dg.as_padded_graph(), cfg, origins=[0], exists=dg.exists,
-        key=jax.random.key(0),
-    )
-    warm, _ = simulate(clone_state(st), cfg, warm_rounds, plan)
-    stages = profile_round_stages(warm, cfg, plan, reps=reps, tails=tails)
-    composed = {
-        impl: round(stages[f"full_round[{impl}]"] * 1e3, 4) for impl in tails
-    }
-    tail_ms = {
-        impl: round(stages[f"tail[{impl}]"] * 1e3, 4) for impl in tails
-    }
-    decision = min(composed, key=composed.get)
-    rec = {
-        "n_peers": dg.n_pad, "mode": cfg.mode, "platform": jax.default_backend(),
-        "tails_measured": list(tails),
-        "tail_ms_per_round": tail_ms,
-        "composed_ms_per_round": composed,
-        "decision": decision,
-        "decision_basis": "fastest composed round (SIR+churn config, all "
-        "tail branches live) on this platform",
-    }
-    if on_cpu:
-        rec["cpu_container_caveat"] = (
-            "pallas tail is interpret-mode on CPU (functional-only, not "
-            "measurable) — this A/B settles reference vs fused only; the "
-            "pallas default stays open until this entry rides a TPU bench "
-            "run, where the pallas row appears automatically"
-        )
-    return rec
-
-
 def bench_packed_ab(n: int = 1_000_000, rounds: int = 8, reps: int = 3):
     """Packed-NATIVE vs unpack/repack round-trip at headline scale (the
     packed-native tentpole's measured claim): the same ``--packed`` loop
@@ -1223,10 +1161,7 @@ def bench_packed_ab(n: int = 1_000_000, rounds: int = 8, reps: int = 3):
 def bench_pipeline(n: int, horizon: int = 24, reps: int = 1):
     """Pipelined vs serial sharded matching rounds at headline scale
     (ISSUE 10 acceptance): ms/round for the serial schedule vs the
-    depth-1 double-buffered exchange on this mesh, with the extended
-    profiler's stage decomposition attributing where the overlap can
-    win (``delivery`` ≈ the issue the collective hides behind; the
-    tail/liveness/stats rows are the shard-local work it hides in).
+    depth-1 double-buffered exchange on this mesh.
 
     Fixed-horizon ``simulate_dist`` on the SAME swarm both ways — the
     pipelined run does identical per-round work (same draws, same
@@ -1249,9 +1184,7 @@ def bench_pipeline(n: int, horizon: int = 24, reps: int = 1):
         make_mesh, shard_matching_plan, shard_swarm, simulate_dist,
     )
     from tpu_gossip.sim import metrics as SM
-    from tpu_gossip.sim.engine import simulate
     from tpu_gossip.sim.stages import compile_pipeline
-    from tpu_gossip.utils.profiling import profile_round_stages
 
     mesh = make_mesh()
     if 128 % mesh.size:
@@ -1294,13 +1227,6 @@ def bench_pipeline(n: int, horizon: int = 24, reps: int = 1):
 
     serial = run(None)
     pipelined = run(compile_pipeline(1))
-    # the local twin's stage decomposition at the same scale: the overlap
-    # attribution table (what the collective can hide behind/in)
-    warm_l, _ = simulate(clone_state(st0), cfg, 4, plan)
-    stages = profile_round_stages(warm_l, cfg, plan, reps=max(reps, 1),
-                                  tails=("fused",))
-    import math as _math
-
     return {
         "n_peers": n, "devices": mesh.size, "mode": cfg.mode,
         "horizon_rounds": horizon,
@@ -1309,21 +1235,13 @@ def bench_pipeline(n: int, horizon: int = 24, reps: int = 1):
         "pipelined_over_serial_ms": round(
             pipelined["ms_per_round"] / max(serial["ms_per_round"], 1e-9), 3
         ),
-        "stage_decomposition_local_ms": {
-            k: (round(v * 1e3, 4) if _math.isfinite(v) else None)
-            for k, v in stages.items()
-        },
         "note": "depth-1 delivery is one round stale (rounds-to-coverage "
         "grows; the recurrence halves the effective hop rate) — the "
         "overlap win is ms/round and per-round-priced throughput. On "
         "this CPU container the all_to_all is a memcpy XLA does not "
         "run concurrently with compute, so the schedule win needs the "
         "real-mesh async collectives; the entry rides every bench run "
-        "so the next hardware run records it without hand work. The "
-        "local decomposition's delivery row interprets the matching "
-        "lane shuffles on CPU (single-process; the dist rounds above "
-        "run them 8-way per shard) — on TPU it is the real ~1.4 ms "
-        "issue the collective hides behind",
+        "so the next hardware run records it without hand work",
     }
 
 
@@ -2244,7 +2162,7 @@ def main(argv: list[str] | None = None) -> int:
     def skip(section: str) -> bool:
         """True (and records the skip) when the budget is too spent for
         ``section`` — the guard that keeps rc=0 with the headline printed."""
-        frac = {"tail_ab": 0.35, "north_star_10m": 0.40, "dist_200k": 0.70,
+        frac = {"north_star_10m": 0.40, "dist_200k": 0.70,
                 "dist_1m": 0.78, "hier_1m": 0.79,
                 "packed_ab_1m": 0.80, "grow_1m": 0.82,
                 "stream_1m": 0.86, "serve_1m": 0.87,
@@ -2358,11 +2276,6 @@ def main(argv: list[str] | None = None) -> int:
         # vs the reference's 30-42 s worst-case band, SURVEY.md §6)
         configs["liveness_1k"] = bench_liveness(reps=reps)
     flush_detail()
-
-    if not quick and not skip("tail_ab"):
-        # the --tail default decision A/B (pallas rows appear on TPU)
-        out["tail_ab"] = bench_tail_ab(dg1, plan1_k1, reps=reps)
-        flush_detail()
 
     if profile_dir:
         # one warmed headline rep under the device tracer (SURVEY.md §5.1)
@@ -2762,12 +2675,6 @@ def _compact(out: dict) -> dict:
             "quorum_over_direct_ms": av["quorum_over_direct_ms"],
             "suspicion_planes_bytes_per_peer":
                 av["suspicion_planes_bytes_per_peer"],
-        }
-    t = out.get("tail_ab")
-    if t and "composed_ms_per_round" in t:
-        compact["tail_ab"] = {
-            "decision": t["decision"],
-            "composed_ms_per_round": t["composed_ms_per_round"],
         }
     pk = out.get("packed_ab_1m")
     if pk and "local" in pk:

@@ -518,7 +518,6 @@ def test_cli_rejects_adversary_scenario_without_quorum(tmp_path):
     ["--quorum-k", "-3"],
     ["--quorum-k", "2", "--suspicion-window", "1"],  # below the grace
     ["--quorum-k", "2", "--accusation-budget", "200"],  # past the cap
-    ["--quorum-k", "2", "--profile-round", "3"],
 ])
 def test_cli_quorum_rejections(argv):
     from tpu_gossip.cli.run_sim import main

@@ -518,8 +518,6 @@ def test_cli_control_rejections(capsys):
                         "--control-bounds", "1,5"]) == 2
     err = capsys.readouterr().err
     assert "rewire" in err
-    # (--profile-round now COMPOSES with --control — the controlled
-    # stage decomposition; pinned in tests/unit/test_profiling.py)
     # flood has no sampled fanout and no pull half — nothing to modulate
     assert _run(BASE + ["--rounds", "20", "--control", "0.9",
                         "--mode", "flood"]) == 2

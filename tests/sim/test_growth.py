@@ -562,8 +562,6 @@ def test_cli_grow_rejections(tmp_path, capsys):
     base = ["--peers", "64", "--rounds", "8", "--slots", "2", "--quiet"]
     assert _run(base + ["--grow", "32"]) == 2
     assert _run(base + ["--grow", "128", "--grow-capacity", "100"]) == 2
-    # (--grow --profile-round now composes: the growth-stage row —
-    # pinned in tests/unit/test_profiling.py)
     assert _run(base + ["--grow", "128", "--shard", "--remat-every", "4"]) == 2
     assert _run(base + ["--grow", "128", "--m", "64"]) == 2
     # join_burst without --grow

@@ -641,8 +641,6 @@ def test_cli_stream_rejections(capsys):
     assert _run(BASE + ["--rounds", "20", "--stream", "-1"]) == 2
     # steady state needs a fixed horizon (run-to-coverage stops on slot 0)
     assert _run(BASE + ["--rounds", "0", "--stream", "2"]) == 2
-    # (--profile-round now COMPOSES with --stream — the loaded stage
-    # decomposition; pinned in tests/unit/test_profiling.py)
     # TTL below the feasible coverage horizon
     assert _run(BASE + ["--rounds", "20", "--stream", "2",
                         "--slot-ttl", "2"]) == 2

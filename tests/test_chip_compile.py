@@ -55,9 +55,10 @@ def sds(topo):
     )
 
 
-def _compile(fn, *args):
+def _compile(fn, *args) -> str:
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "the kernel did not lower to Mosaic"
+    return text
 
 
 def test_interpret_follows_the_target_device(topo):
@@ -85,11 +86,13 @@ def test_fold_planes(sds):
     from tpu_gossip.kernels.permute import fold_planes
 
     slots = sds((65536, 128), jnp.int32)
-    _compile(
+    text = _compile(
         lambda s: fold_planes(s, 0, 512 * 1024, 500_000, 16,
                               interpret=False),
         slots,
     )
+    # the kernel's name, which a device trace calls it by
+    assert "%fold_planes" in text
 
 
 def _staircase_plan(sds, fanout):

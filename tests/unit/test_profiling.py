@@ -25,137 +25,3 @@ def test_trace_disabled_is_noop(tmp_path):
     with trace(""):
         pass
     assert list(tmp_path.iterdir()) == []
-
-
-def test_slope_time_measures_positive_per_iteration_cost():
-    from tpu_gossip.utils.profiling import slope_time
-
-    x = jnp.arange(1 << 16, dtype=jnp.int32)
-
-    def body(i, c, arr):
-        return c ^ jnp.sum(arr + i, dtype=jnp.int32)
-
-    dt = slope_time(body, jnp.int32(0), 2, 50, reps=2, operands=(x,))
-    assert dt == dt and dt > 0  # finite, positive
-
-
-@pytest.mark.slow  # slope-timing every stage is wall-heavy; the CLI test
-# below drives the same decomposition and stays in tier-1
-def test_profile_round_stages_covers_every_stage():
-    """The stage decomposition (run_sim --profile-round): every declared
-    stage present, tails selectable, values floats (NaN allowed at toy
-    scales where noise wins the slope)."""
-    import numpy as np
-
-    from tpu_gossip import SwarmConfig, build_csr, init_swarm, preferential_attachment
-    from tpu_gossip.core.state import clone_state
-    from tpu_gossip.sim.engine import simulate
-    from tpu_gossip.utils.profiling import (
-        format_stage_table, profile_round_stages,
-    )
-
-    n = 512
-    g = build_csr(n, preferential_attachment(n, m=3, use_native=False))
-    cfg = SwarmConfig(
-        n_peers=n, msg_slots=8, fanout=2, mode="push_pull",
-        churn_leave_prob=0.02, churn_join_prob=0.1, rewire_slots=2,
-    )
-    st = init_swarm(g, cfg, origins=[0], key=jax.random.key(0))
-    st, _ = simulate(clone_state(st), cfg, 3)
-    stages = profile_round_stages(
-        st, cfg, None, reps=1, loop_lengths=(2, 6),
-        tails=("reference", "fused", "pallas"),
-    )
-    want = {
-        "delivery", "liveness", "stats", "rng",
-        "tail[reference]", "tail[fused]", "tail[pallas]",
-        "full_round[reference]", "full_round[fused]", "full_round[pallas]",
-    }
-    assert set(stages) == want
-    assert all(isinstance(v, float) for v in stages.values())
-    table = format_stage_table(stages)
-    assert "| stage | ms/round |" in table and "tail[fused]" in table
-
-
-@pytest.mark.slow  # composed-planes variant of the stage decomposition
-def test_profile_round_stages_composed_planes():
-    """PR 10 satellite: the decomposition covers the post-PR-3 stages —
-    growth / stream / control rows appear when compiled planes are
-    passed, and the transport_compact probe measures the sparse lane's
-    compaction round-trip."""
-    import numpy as np
-
-    from tpu_gossip import SwarmConfig, build_csr, init_swarm, preferential_attachment
-    from tpu_gossip.control import compile_control
-    from tpu_gossip.core.state import clone_state
-    from tpu_gossip.growth import compile_growth
-    from tpu_gossip.sim.engine import simulate
-    from tpu_gossip.traffic import compile_stream
-    from tpu_gossip.utils.profiling import profile_round_stages
-
-    n = 256
-    g = build_csr(n, preferential_attachment(n, m=3, use_native=False))
-    cfg = SwarmConfig(n_peers=n, msg_slots=8, fanout=2, mode="push_pull",
-                      rewire_slots=2)
-    st = init_swarm(g, cfg, origins=[0], key=jax.random.key(0))
-    gp = compile_growth(n_initial=n - 32, target=n, n_slots=n,
-                        joins_per_round=4, attach_m=2,
-                        admit_rows=np.arange(n - 32, n))
-    sp = compile_stream(rate=1.0, msg_slots=8, ttl=8,
-                        origin_rows=np.arange(n - 32))
-    cp = compile_control(target_ratio=0.9, fanout=2, lo=1, hi=2)
-    st, _ = simulate(clone_state(st), cfg, 2, growth=gp, stream=sp,
-                     control=cp)
-    stages = profile_round_stages(
-        st, cfg, None, reps=1, loop_lengths=(2, 6), tails=("fused",),
-        growth=gp, stream=sp, control=cp,
-        transport_probe=(8, 1024, 1, 128),
-    )
-    for row in ("growth", "stream", "control", "transport_compact",
-                "full_round[fused]"):
-        assert row in stages, row
-    assert all(isinstance(v, float) for v in stages.values())
-
-
-@pytest.mark.slow  # planes-composed CLI variant; the plain CLI profile
-# test remains the tier-1 witness
-def test_run_sim_profile_round_cli_composes_with_planes(capsys):
-    """run_sim --profile-round with --grow/--stream/--control runs the
-    composed decomposition (the old parse-time rejections are gone) and
-    the summary JSON carries the new rows."""
-    import json
-
-    from tpu_gossip.cli.run_sim import main as run_sim_main
-
-    rc = run_sim_main([
-        "--peers", "96", "--slots", "4", "--fanout", "2", "--quiet",
-        "--mode", "push_pull", "--profile-round", "1",
-        "--grow", "128", "--m", "2", "--stream", "1", "--control", "0.9",
-    ])
-    assert rc == 0
-    row = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    for k in ("growth", "stream", "control", "transport_compact"):
-        assert k in row["stages_ms"], k
-
-
-@pytest.mark.slow  # full profile table over a real run; slope_time and the
-# no-op trace contract keep the profiling util in tier-1
-def test_run_sim_profile_round_cli(capsys):
-    import json
-
-    from tpu_gossip.cli.run_sim import main as run_sim_main
-
-    rc = run_sim_main([
-        "--peers", "256", "--mode", "push_pull", "--fanout", "2",
-        "--profile-round", "2", "--quiet",
-    ])
-    assert rc == 0
-    out = capsys.readouterr().out.strip().splitlines()[-1]
-    row = json.loads(out)  # strict JSON — NaNs must have become null
-    assert row["profile_round"] is True
-    assert "tail[fused]" in row["stages_ms"]
-    # --shard is the dist engines' territory: loud exit, not silence
-    rc = run_sim_main([
-        "--peers", "64", "--profile-round", "1", "--shard",
-    ])
-    assert rc == 2

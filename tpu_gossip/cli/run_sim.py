@@ -160,15 +160,6 @@ def build_parser() -> argparse.ArgumentParser:
         "Requires --shard — the overlap targets the mesh collectives",
     )
     p.add_argument(
-        "--profile-round", type=int, default=0, metavar="R",
-        help="instead of the normal run: advance R warm rounds, then "
-        "slope-time the round's stage decomposition (delivery, tail per "
-        "implementation, liveness, stats, rng, composed round — "
-        "utils.profiling.profile_round_stages) and print it as the summary "
-        "JSON. Local engine only; the published table lives in "
-        "docs/round_tail_profile.md",
-    )
-    p.add_argument(
         "--grow", type=int, default=0, metavar="TARGET_N",
         help="grow the swarm to TARGET_N peers while gossiping (growth/, "
         "docs/growth_engine.md): per-round join batches are admitted "
@@ -349,9 +340,8 @@ def build_parser() -> argparse.ArgumentParser:
         "decoding full width only at licensed stages, and the "
         "trajectory — state AND integer stats — is BIT-IDENTICAL to "
         "the unpacked run (test-pinned across the composed matrix). "
-        "Works on every engine path except --profile-round and the "
-        "remat epoch loops (which fold the unpacked CSR between "
-        "segments)",
+        "Works on every engine path except the remat epoch loops "
+        "(which fold the unpacked CSR between segments)",
     )
     p.add_argument(
         "--builder", choices=["local", "dist"], default="local",
@@ -469,10 +459,6 @@ def _run(args, resume=None) -> int:
                     file=sys.stderr,
                 )
             return 2
-        if args.profile_round > 0:
-            print("--profile-round measures the fault-free round's stage "
-                  "decomposition; drop --scenario", file=sys.stderr)
-            return 2
         if args.shard and args.remat_every > 0 and spec.uses_node_sets:
             print("--scenario with node-scoped faults cannot compose with "
                   "--shard --remat-every: the epoch re-partition permutes "
@@ -499,16 +485,6 @@ def _run(args, resume=None) -> int:
     ckpt_err = _validate_ckpt(args)
     if ckpt_err:
         print(ckpt_err, file=sys.stderr)
-        return 2
-    if args.profile_round > 0 and args.shard:
-        print("--profile-round decomposes the LOCAL round (use "
-              "experiments/dist_profile.py for the mesh engines)",
-              file=sys.stderr)
-        return 2
-    if args.packed and args.profile_round > 0:
-        print("--profile-round decomposes the UNPACKED round's stages; "
-              "the packed carry adds only the boundary codec — drop "
-              "--packed for the decomposition", file=sys.stderr)
         return 2
     if args.packed and args.remat_every > 0:
         print("--packed cannot compose with --remat-every: the epoch "
@@ -639,19 +615,6 @@ def _run(args, resume=None) -> int:
         state.silent = state.silent.at[silent_ids].set(True)
 
     from tpu_gossip.utils.profiling import trace
-
-    if args.profile_round > 0:
-        # the decomposition composes with the post-PR-3 planes: a growing
-        # / loaded / controlled profile measures those stages too
-        grow_p = _compile_cli_growth(args, spec, n_slots=graph.n, mplan=mplan)
-        strm_p = _compile_cli_stream(
-            args,
-            np.flatnonzero(np.asarray(exists)) if exists is not None
-            else np.arange(graph.n),
-        )
-        ctl_p = _compile_cli_control(args)
-        return _main_profile_round(args, cfg, state, plan, grow_p, strm_p,
-                                   ctl_p)
 
     scen = _compile_cli_scenario(spec, args, n_slots=graph.n)
     grow = _compile_cli_growth(args, spec, n_slots=graph.n, mplan=mplan)
@@ -1239,9 +1202,7 @@ def _validate_stream(args):
 
     if args.stream < 0:
         return f"--stream {args.stream} must be a non-negative arrival rate"
-    if args.rounds <= 0 and args.profile_round == 0:
-        # (--profile-round slope-times stages instead of running a
-        # horizon, so the steady-state requirement does not bind it)
+    if args.rounds <= 0:
         return ("--stream measures a steady state over a fixed horizon — "
                 "run-to-coverage stops on slot 0, which the age-out "
                 "recycles; pass --rounds R (R >> --slot-ttl)")
@@ -1386,9 +1347,6 @@ def _validate_liveness(args, spec):
         return (f"--accusation-budget {args.accusation_budget} outside "
                 f"[0, {SUSPECT_STRIKE_CAP}] (the packed strike counter's "
                 "range; 0 disables quarantine)")
-    if args.profile_round > 0:
-        return ("--profile-round measures the unhardened round's stage "
-                "decomposition; drop --quorum-k")
     return None
 
 
@@ -1496,9 +1454,6 @@ def _validate_ckpt(args):
                 "run-to-coverage loop is a single on-device while_loop "
                 "with no deterministic segment grid to cut at — pass "
                 "--rounds R")
-    if args.profile_round > 0:
-        return ("--profile-round slope-times the round's stages instead "
-                "of running a horizon; drop the checkpoint flags")
     if args.keep < 0 or args.checkpoint_shards < 0:
         return "--keep and --checkpoint-shards must be >= 0"
     if args.checkpoint_every >= args.rounds:
@@ -1911,63 +1866,6 @@ def _scenario_summary(spec, stats=None) -> dict:
 
         out["phases"] = M.phase_report(stats, spec)
     return out
-
-
-def _main_profile_round(args, cfg, state, plan, grow=None, strm=None,
-                        ctl=None) -> int:
-    """--profile-round R: the slope-timed stage decomposition of one round.
-
-    Advances R rounds first (mid-epidemic slot densities — a cold state
-    makes every stage trivially sparse; with growth/stream/control the
-    warm rounds run those planes so the registry/lease/cursor state is
-    mid-flight too), then times each stage and the composed round per
-    tail implementation. The post-PR-3 stages ride along: ``growth`` /
-    ``stream`` / ``control`` rows appear when the matching flags are
-    set, and ``transport_compact`` always measures the sparse lane's
-    shard-local compaction round-trip at this swarm's synthetic 8-shard
-    bucket geometry (the dims the dist engine would use). The summary
-    JSON carries ms-per-round figures; the human-readable table goes to
-    stderr.
-    """
-    from tpu_gossip.core.state import clone_state
-    from tpu_gossip.kernels.pallas_segment import _slot_groups
-    from tpu_gossip.sim.engine import simulate
-    from tpu_gossip.utils.profiling import (
-        format_stage_table, profile_round_stages, trace,
-    )
-
-    warm, _ = simulate(clone_state(state), cfg, args.profile_round, plan,
-                       growth=grow, stream=strm, control=ctl)
-    tails = ("reference", "fused") if args.tail != "pallas" else (
-        "reference", "fused", "pallas",
-    )
-    # synthetic dist bucket geometry for the compaction probe: 8 shards,
-    # capacity = per-(src,dst)-pair directed edges rounded to whole
-    # 1024-entry windows (partition_graph's law), budget = 1/8 of it
-    # (build_transport's default compact_frac)
-    s_probe = 8
-    e_real = int(np.asarray(state.row_ptr)[-1])
-    b_probe = max(1024, -(-e_real // (s_probe * s_probe * 1024)) * 1024)
-    probe = (s_probe, b_probe, len(_slot_groups(args.slots)),
-             max(b_probe // 8, 1))
-    with trace(args.profile):  # --profile DIR composes: xprof the stages
-        stages = profile_round_stages(warm, cfg, plan, tails=tails,
-                                      growth=grow, stream=strm, control=ctl,
-                                      transport_probe=probe)
-    print(format_stage_table(stages), file=sys.stderr)
-    import math
-
-    print(json.dumps({
-        "summary": True, "profile_round": True, "mode": args.mode,
-        "n_peers": args.peers, "warm_rounds": args.profile_round,
-        # NaN (slope lost to noise at tiny scales) -> null: the summary
-        # line must stay strictly parseable JSON
-        "stages_ms": {
-            k: (round(v * 1e3, 4) if math.isfinite(v) else None)
-            for k, v in stages.items()
-        },
-    }))
-    return 0
 
 
 def _run_with_remat(args, cfg, state, scen=None, grow=None, strm=None,
@@ -2934,8 +2832,6 @@ def _validate_serve(args):
         return ("serve double-buffers the injection window against the "
                 "in-flight device round itself (serve/driver.py); "
                 "--pipeline's exchange overlap does not compose with it")
-    if args.profile_round > 0:
-        return "--profile-round decomposes the offline round; drop it for serve"
     if args.transport != "dense":
         return (f"--transport {args.transport} is not wired through the "
                 "serving driver; run the transport A/B offline")

@@ -780,6 +780,7 @@ def message_slots(
     return tuple(out)
 
 
+@jax.profiler.annotate_function
 def init_swarm(
     graph: Graph,
     config: SwarmConfig,
@@ -799,6 +800,10 @@ def init_swarm(
     ``DeviceGraph``-backed CSR) — per-peer state is constructed on device, so
     nothing peer-sized crosses the host link. ``exists`` marks real peer
     slots (default all); non-existent slots (pads/sentinels) start dead.
+
+    The call runs inside a host ``jax.profiler.TraceAnnotation`` named
+    ``init_swarm``: in a profiler trace the span shares the device ops'
+    clock, so the reset's dispatches can be laid against it.
     """
     if graph.n != config.n_peers:
         raise ValueError(f"graph has {graph.n} nodes but config.n_peers={config.n_peers}")

@@ -1292,6 +1292,7 @@ def run_until_coverage_dist(
     """
     from tpu_gossip.dist.transport import accumulate_ici, zero_ici_totals
 
+    @jax.named_scope("coverage")
     def cond_plain(st) -> jax.Array:
         # PackedSwarm reads coverage off its packed words (one bit
         # column); the definition matches SwarmState.coverage exactly

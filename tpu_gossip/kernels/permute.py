@@ -80,6 +80,7 @@ def _shuffle_call(x, idx, rows, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.named_scope("lane_shuffle")
 def lane_shuffle(
     x: jax.Array, idx: jax.Array, *, interpret: bool | None = None
 ) -> jax.Array:
@@ -266,12 +267,14 @@ def fold_planes(
     base = slot_off // 1024
     step = cstride // 1024
 
-    out = pl.pallas_call(
-        _fold_kernel(op),
-        grid=(step, pad_deg),
-        in_specs=[pl.BlockSpec((8, 128), lambda j, i: (base + i * step + j, 0))],
-        out_specs=pl.BlockSpec((8, 128), lambda j, i: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((cstride // 128, 128), slots2d.dtype),
-        interpret=interpret,
-    )(slots2d)
-    return out.reshape(-1)[:count]
+    with jax.named_scope("fold_planes"):
+        out = pl.pallas_call(
+            _fold_kernel(op),
+            grid=(step, pad_deg),
+            in_specs=[pl.BlockSpec((8, 128), lambda j, i: (base + i * step + j, 0))],
+            out_specs=pl.BlockSpec((8, 128), lambda j, i: (j, 0)),
+            out_shape=jax.ShapeDtypeStruct((cstride // 128, 128), slots2d.dtype),
+            interpret=interpret,
+            name="fold_planes",
+        )(slots2d)
+        return out.reshape(-1)[:count]

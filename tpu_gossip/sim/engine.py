@@ -1004,9 +1004,10 @@ def advance_round(
         rng=key,
         round=rnd,
     )
-    return new_state, _stats(new_state, msgs_sent, fstats, growth, stream,
-                             values["stel"], values["ctel"],
-                             values["ltel"], liveness, values["itel"])
+    with jax.named_scope("stats"):
+        return new_state, _stats(new_state, msgs_sent, fstats, growth,
+                                 stream, values["stel"], values["ctel"],
+                                 values["ltel"], liveness, values["itel"])
 
 
 def gossip_round(
@@ -1207,6 +1208,7 @@ def run_until_coverage(
     bit-identical to the unpacked loop.
     """
 
+    @jax.named_scope("coverage")
     def cond(s) -> jax.Array:
         return (s.coverage(slot) < target) & (s.round - state.round < max_rounds)
 

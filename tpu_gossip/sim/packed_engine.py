@@ -491,11 +491,12 @@ def advance_round_packed(
         round=rnd,
         msg_slots=ps.msg_slots,
     )
-    return new_state, _stats_packed(
-        new_state, values, msgs_sent, fstats, growth, stream,
-        values["stel"], values["ctel"], values["ltel"], liveness,
-        values["itel"],
-    )
+    with jax.named_scope("stats"):
+        return new_state, _stats_packed(
+            new_state, values, msgs_sent, fstats, growth, stream,
+            values["stel"], values["ctel"], values["ltel"], liveness,
+            values["itel"],
+        )
 
 
 def _stats_packed(
@@ -583,6 +584,7 @@ def _stats_packed(
     )
 
 
+@jax.named_scope("round")
 def run_protocol_round_packed(
     ps: PackedSwarm,
     cfg,
@@ -621,62 +623,64 @@ def run_protocol_round_packed(
     _engine.validate_rewire_width(ps, cfg)
     m = ps.msg_slots
     rnd = ps.round + 1
-    key, k_push, k_pull, k_leave, k_join = jax.random.split(ps.rng, 5)
-    flags = _decode_flags(ps)
-    _active, role_w, tx_w = packed_round_head(ps, cfg, flags, liveness)
-    rctl = None
-    if control is not None:
-        from tpu_gossip.control.engine import control_round
+    with jax.named_scope("roles"):
+        key, k_push, k_pull, k_leave, k_join = jax.random.split(ps.rng, 5)
+        flags = _decode_flags(ps)
+        _active, role_w, tx_w = packed_round_head(ps, cfg, flags, liveness)
+        rctl = None
+        if control is not None:
+            from tpu_gossip.control.engine import control_round
 
-        # control reads slot coverage off a genuine (N, M) plane
-        rctl = control_round(
-            control,
-            types.SimpleNamespace(
-                control_lvl=ps.control_lvl, alive=flags["alive"],
-                declared_dead=flags["declared_dead"],
-                seen=unpack_bits(ps.seen, m), slot_lease=ps.slot_lease,
-            ),
-            want_needy=cfg.mode == "push_pull",
-        )
-    k_accuse = k_forge = k_flood = None
-    if scenario is not None and scenario.has_adversary:
-        from tpu_gossip.core.streams import ADVERSARY_STREAM_SALT
-
-        k_accuse, k_forge, k_flood = jax.random.split(
-            jax.random.fold_in(ps.rng, ADVERSARY_STREAM_SALT), 3
-        )
-    if scenario is None:
-        inc_w, msgs_sent = deliver_words(
-            tx_w, role_w, flags, k_push, k_pull, rctl
-        )
-        tx_eff_w, held_w, telem, rf = tx_w, None, None, None
-    else:
-        from tpu_gossip.faults.inject import scenario_dissemination
-
-        # the fault head latches bool planes (hold buffers, blackout
-        # masks): decode the round's planes once, run the bool head +
-        # bool delivery, pack the products
-        seen_b = unpack_bits(ps.seen, m)
-        role_b = unpack_bits(role_w, m)
-        shim = types.SimpleNamespace(
-            rng=ps.rng, alive=flags["alive"],
-            declared_dead=flags["declared_dead"],
-            quarantine=flags["quarantine"],
-            fault_held=unpack_bits(ps.fault_held, m),
-            seen=seen_b,
-        )
-        deliver = deliver_bool_factory(flags, seen_b)
-        incoming, msgs_sent, tx_eff, held, telem, rf = (
-            scenario_dissemination(
-                scenario, shim, rnd, unpack_bits(tx_w, m), role_b, role_b,
-                k_push, k_pull,
-                lambda tx, tr, rc, kp, kq: deliver(tx, tr, rc, kp, kq, rctl),
-                k_flood=k_flood,
+            # control reads slot coverage off a genuine (N, M) plane
+            rctl = control_round(
+                control,
+                types.SimpleNamespace(
+                    control_lvl=ps.control_lvl, alive=flags["alive"],
+                    declared_dead=flags["declared_dead"],
+                    seen=unpack_bits(ps.seen, m), slot_lease=ps.slot_lease,
+                ),
+                want_needy=cfg.mode == "push_pull",
             )
-        )
-        inc_w = pack_bits(incoming)
-        tx_eff_w = pack_bits(tx_eff)
-        held_w = None if held is None else pack_bits(held)
+        k_accuse = k_forge = k_flood = None
+        if scenario is not None and scenario.has_adversary:
+            from tpu_gossip.core.streams import ADVERSARY_STREAM_SALT
+
+            k_accuse, k_forge, k_flood = jax.random.split(
+                jax.random.fold_in(ps.rng, ADVERSARY_STREAM_SALT), 3
+            )
+    with jax.named_scope("delivery"):
+        if scenario is None:
+            inc_w, msgs_sent = deliver_words(
+                tx_w, role_w, flags, k_push, k_pull, rctl
+            )
+            tx_eff_w, held_w, telem, rf = tx_w, None, None, None
+        else:
+            from tpu_gossip.faults.inject import scenario_dissemination
+
+            # the fault head latches bool planes (hold buffers, blackout
+            # masks): decode the round's planes once, run the bool head +
+            # bool delivery, pack the products
+            seen_b = unpack_bits(ps.seen, m)
+            role_b = unpack_bits(role_w, m)
+            shim = types.SimpleNamespace(
+                rng=ps.rng, alive=flags["alive"],
+                declared_dead=flags["declared_dead"],
+                quarantine=flags["quarantine"],
+                fault_held=unpack_bits(ps.fault_held, m),
+                seen=seen_b,
+            )
+            deliver = deliver_bool_factory(flags, seen_b)
+            incoming, msgs_sent, tx_eff, held, telem, rf = (
+                scenario_dissemination(
+                    scenario, shim, rnd, unpack_bits(tx_w, m), role_b, role_b,
+                    k_push, k_pull,
+                    lambda tx, tr, rc, kp, kq: deliver(tx, tr, rc, kp, kq, rctl),
+                    k_flood=k_flood,
+                )
+            )
+            inc_w = pack_bits(incoming)
+            tx_eff_w = pack_bits(tx_eff)
+            held_w = None if held is None else pack_bits(held)
     pipe_buf_w = None
     if pipeline is not None and pipeline.depth > 0:
         inc_w, pipe_buf_w = ps.pipe_buf, inc_w

@@ -144,8 +144,9 @@ def run_stages(stages: tuple[Stage, ...], values: dict) -> dict:
     Stages run in declared order (the DAG is linearized at build time —
     each stage's reads must be satisfied by the initial carries or an
     earlier stage's writes). Enforced per stage: every declared read
-    exists, every returned key was declared. Mutates and returns
-    ``values``.
+    exists, every returned key was declared. Each stage traces inside
+    ``jax.named_scope(stage.name)``, so its ops carry the stage's name in
+    a device trace. Mutates and returns ``values``.
     """
     for st in stages:
         missing = [k for k in st.reads if k not in values]
@@ -155,7 +156,8 @@ def run_stages(stages: tuple[Stage, ...], values: dict) -> dict:
                 f"earlier stage or initial carry provides — stage order "
                 f"or declarations are wrong"
             )
-        out = st.fn(StageView(values, st))
+        with jax.named_scope(st.name):
+            out = st.fn(StageView(values, st))
         undeclared = [k for k in out if k not in st.writes]
         if undeclared:
             raise ValueError(
@@ -736,6 +738,7 @@ def effective_transmit_planes(state, cfg, scenario=None):
     return transmit, transmitter, receptive
 
 
+@jax.named_scope("round")
 def run_protocol_round(
     state,
     cfg,
@@ -778,6 +781,12 @@ def run_protocol_round(
     (traffic/ingest.py) — deterministic data, no randomness consumed,
     so ``inject=None`` and a zero-count batch reproduce the uninjected
     trajectory bit for bit.
+
+    The round traces inside ``jax.named_scope("round")``, with ``roles``
+    (key split, role masks, control resolve) and ``delivery`` (the
+    engine's dissemination, scenario head included) inside it beside the
+    stages' own scopes: names a device trace attributes time by
+    (utils/profiling.py).
     """
     from tpu_gossip.sim import engine as _engine
 
@@ -791,52 +800,54 @@ def run_protocol_round(
         )
     _engine.validate_rewire_width(state, cfg)
     rnd = state.round + 1
-    key, k_push, k_pull, k_leave, k_join = jax.random.split(state.rng, 5)
-    _, transmitter, receptive = _engine.compute_roles(state)
-    transmit = _engine.transmit_bitmap(state, cfg, transmitter)
-    if liveness is not None:
-        # the quarantine verdict masks a peer's SENDS (its pushes offer
-        # nothing; it still receives and still counts as a live member —
-        # it is a suspected liar, not a purged one). The no-defense path
-        # never reads the plane, so unhardened rounds stay bit-identical
-        # to pre-defense ones.
-        transmit = transmit & ~state.quarantine[:, None]
-    rctl = None
-    if control is not None:
-        from tpu_gossip.control.engine import control_round
+    with jax.named_scope("roles"):
+        key, k_push, k_pull, k_leave, k_join = jax.random.split(state.rng, 5)
+        _, transmitter, receptive = _engine.compute_roles(state)
+        transmit = _engine.transmit_bitmap(state, cfg, transmitter)
+        if liveness is not None:
+            # the quarantine verdict masks a peer's SENDS (its pushes offer
+            # nothing; it still receives and still counts as a live member —
+            # it is a suspected liar, not a purged one). The no-defense path
+            # never reads the plane, so unhardened rounds stay bit-identical
+            # to pre-defense ones.
+            transmit = transmit & ~state.quarantine[:, None]
+        rctl = None
+        if control is not None:
+            from tpu_gossip.control.engine import control_round
 
-        rctl = control_round(control, state,
-                             want_needy=cfg.mode == "push_pull")
-    k_accuse = k_forge = k_flood = None
-    if scenario is not None and scenario.has_adversary:
-        # ONE fold of the registered adversary salt per round (the
-        # lineage contract: a (parent, salt) pair folds once), split into
-        # the three per-round attack children — all consumed at GLOBAL
-        # shape, so adversarial rounds keep the local↔sharded
-        # bit-identity contract
-        from tpu_gossip.core.streams import ADVERSARY_STREAM_SALT
+            rctl = control_round(control, state,
+                                 want_needy=cfg.mode == "push_pull")
+        k_accuse = k_forge = k_flood = None
+        if scenario is not None and scenario.has_adversary:
+            # ONE fold of the registered adversary salt per round (the
+            # lineage contract: a (parent, salt) pair folds once), split into
+            # the three per-round attack children — all consumed at GLOBAL
+            # shape, so adversarial rounds keep the local↔sharded
+            # bit-identity contract
+            from tpu_gossip.core.streams import ADVERSARY_STREAM_SALT
 
-        k_accuse, k_forge, k_flood = jax.random.split(
-            jax.random.fold_in(state.rng, ADVERSARY_STREAM_SALT), 3
-        )
-    if scenario is None:
-        incoming, msgs_sent = disseminate(
-            transmit, transmitter, receptive, k_push, k_pull, rctl
-        )
-        tx_eff, held, telem, rf = transmit, None, None, None
-    else:
-        from tpu_gossip.faults.inject import scenario_dissemination
-
-        incoming, msgs_sent, tx_eff, held, telem, rf = (
-            scenario_dissemination(
-                scenario, state, rnd, transmit, transmitter, receptive,
-                k_push, k_pull,
-                lambda tx, tr, rc, kp, kq: disseminate(
-                    tx, tr, rc, kp, kq, rctl
-                ),
-                k_flood=k_flood,
+            k_accuse, k_forge, k_flood = jax.random.split(
+                jax.random.fold_in(state.rng, ADVERSARY_STREAM_SALT), 3
             )
-        )
+    with jax.named_scope("delivery"):
+        if scenario is None:
+            incoming, msgs_sent = disseminate(
+                transmit, transmitter, receptive, k_push, k_pull, rctl
+            )
+            tx_eff, held, telem, rf = transmit, None, None, None
+        else:
+            from tpu_gossip.faults.inject import scenario_dissemination
+
+            incoming, msgs_sent, tx_eff, held, telem, rf = (
+                scenario_dissemination(
+                    scenario, state, rnd, transmit, transmitter, receptive,
+                    k_push, k_pull,
+                    lambda tx, tr, rc, kp, kq: disseminate(
+                        tx, tr, rc, kp, kq, rctl
+                    ),
+                    k_flood=k_flood,
+                )
+            )
     pipe_buf = None
     if pipeline is not None and pipeline.depth > 0:
         # the double-buffer swap: deliver LAST round's issued exchange,

@@ -1,38 +1,28 @@
-"""Profiler tracing hook + per-stage round decomposition (SURVEY.md §5.1).
+"""Profiler tracing hook (SURVEY.md §5.1).
 
 The reference's only visibility into runtime behavior is timestamped log
 lines (reference Peer.py:40-49, Seed.py:78-87) — "log-line archaeology".
-The TPU-native replacement is two tools:
+The TPU-native replacement is a device trace of the real composed round:
+:func:`trace` wraps any region (a run, a ``simulate()`` horizon) and XLA
+records per-op device timelines, viewable in TensorBoard / Perfetto
+(``xprof``). Exposed as ``--profile DIR`` on ``cli/run_sim.py``.
 
-- :func:`trace` — a real device trace: wrap any region (a bench run, a
-  simulate() horizon) and XLA records per-op device timelines viewable in
-  TensorBoard / Perfetto (`xprof`). Exposed as ``--profile DIR`` on
-  ``bench.py`` and ``cli/run_sim.py``.
-- :func:`profile_round_stages` — a slope-timed decomposition of ONE
-  composed gossip round into its stages (delivery, the protocol tail per
-  implementation, liveness, stats, RNG) using the two-point fori_loop
-  method bench.py's hardware ceilings use: time the same on-device loop at
-  two iteration counts and divide the difference, so constant
-  dispatch+fetch latency cancels. Exposed as ``--profile-round`` on
-  ``cli/run_sim.py``; the published table lives in
-  docs/round_tail_profile.md. Every stage body folds its outputs into an
-  int32 carry (keeps the work live against DCE) — all stages pay that one
-  reduction, so relative comparisons are fair.
+The round names its parts with ``jax.named_scope`` (``sim/stages.py``):
+``round`` with ``roles``, ``delivery``, ``stats`` and each stage of the
+DAG inside it, the run-to-coverage predicate ``coverage``, and the
+delivery kernels ``lane_shuffle`` and ``fold_planes``. XLA keeps the scope
+in each instruction's ``op_name`` metadata, so an op of the trace maps to
+its stage through the compiled program's text, under names that hold
+across XLA's renumbering of fusions (docs/round_tail_profile.md).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
 from pathlib import Path
 from typing import Iterator
 
-__all__ = [
-    "trace",
-    "slope_time",
-    "profile_round_stages",
-    "format_stage_table",
-]
+__all__ = ["trace"]
 
 
 @contextlib.contextmanager
@@ -56,307 +46,3 @@ def trace(log_dir: str | Path | None) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def slope_time(body, carry, n1: int, n2: int, reps: int = 3, operands=()) -> float:
-    """Per-iteration seconds of an on-device ``fori_loop`` body.
-
-    Two-point slope: run the loop at ``n1`` and ``n2`` iterations and
-    divide the wall delta by ``n2 - n1`` — the constant per-dispatch +
-    result-fetch latency cancels exactly (the method bench.py's hardware
-    ceilings use). ``body(i, carry, *operands) -> carry``; the first leaf
-    of the final carry is host-fetched as the completion barrier. Min wall
-    over ``reps``. Returns NaN when noise wins (non-positive slope).
-
-    Pass the body's large device arrays via ``operands`` (a pytree), NOT as
-    closure captures: a closed-over concrete array becomes an XLA CONSTANT
-    in the traced loop, and XLA's compile-time constant folding then
-    evaluates whole (N, M)-sized expressions op by op — tens of seconds of
-    compile per stage at 1M, for numbers that measure the folder instead of
-    the program. Operands are jit arguments, so they stay runtime inputs.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    def run(iters: int) -> float:
-        @jax.jit
-        def f(c, ops):
-            return jax.lax.fori_loop(
-                0, iters, lambda i, cc: body(i, cc, *ops), c
-            )
-
-        out = f(carry, operands)
-        _ = float(jnp.sum(jax.tree_util.tree_leaves(out)[0]))  # warm + barrier
-        best = float("inf")
-        for _rep in range(max(reps, 1)):
-            t0 = time.perf_counter()
-            out = f(carry, operands)
-            _ = float(jnp.sum(jax.tree_util.tree_leaves(out)[0]))
-            best = min(best, time.perf_counter() - t0)
-        return best
-
-    dt = (run(n2) - run(n1)) / (n2 - n1)
-    return dt if dt > 0 else float("nan")
-
-
-def profile_round_stages(
-    state,
-    cfg,
-    plan=None,
-    *,
-    reps: int = 3,
-    loop_lengths: tuple[int, int] = (4, 24),
-    tails: tuple[str, ...] = ("reference", "fused"),
-    growth=None,
-    stream=None,
-    control=None,
-    transport_probe: tuple[int, int, int, int] | None = None,
-) -> dict[str, float]:
-    """Stage decomposition of one composed round, in seconds per round.
-
-    Stages (each an independent slope measurement on the SAME state —
-    pre-run a few rounds first so slot densities are mid-epidemic):
-
-    - ``delivery``            — the dissemination stage alone
-      (``_disseminate_local`` with the given plan), fresh key per iter
-    - ``tail[<impl>]``        — the fused/reference/pallas protocol tail
-      (kernels/round_tail.py) over one delivery's ``incoming``
-    - ``liveness``            — heartbeat emission + failure-detector sweep
-    - ``stats``               — the per-round RoundStats reductions (with
-      the active planes' tracks when growth/stream are passed)
-    - ``rng``                 — the round's key splits
-    - ``growth``              — the admission stage (growth/engine.
-      apply_growth: Gumbel-top-k draw + registry scatters), when a
-      compiled ``growth`` schedule is passed
-    - ``stream``              — the streaming stage (traffic/engine:
-      slot_expiry + apply_stream's landing scan), when a compiled
-      ``stream`` workload is passed
-    - ``control``             — the adaptive-control stage (control/
-      engine: the level resolve + AIMD feedback + PeerSwap refresh),
-      when a compiled ``control`` policy is passed
-    - ``transport_compact``   — the sparse transport's compaction
-      round-trip (dist/transport.py: occupancy header + compact index +
-      gather + scatter) over a synthetic ``transport_probe = (s, b, g,
-      budget)`` payload — the shard-local cost the sparse lane adds
-      around each collective
-    - ``full_round[<impl>]``  — the composed ``gossip_round`` per tail,
-      with every passed plane active
-
-    ``tails`` picks the tail implementations measured (add "pallas" for the
-    single-launch kernel — interpret-mode on CPU, so only meaningful on
-    TPU). Stage sums need not equal the full round: XLA fuses across stage
-    boundaries inside the composed round; the decomposition bounds each
-    stage's isolated cost, the composed rows measure reality. The
-    per-stage table is what attributes a pipelined round's overlap win
-    (docs/pipelined_rounds.md): ``delivery`` is the issue the collective
-    hides behind, everything else is the shard-local work it hides in.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from tpu_gossip.kernels.round_tail import round_tail
-    from tpu_gossip.sim import engine
-
-    n1, n2 = loop_lengths
-    _, transmitter, receptive = engine.compute_roles(state)
-    transmit = engine.transmit_bitmap(state, cfg, transmitter)
-
-    @jax.jit
-    def one_delivery(key, st, tx, tr, rc, pl):
-        k_push, k_pull = jax.random.split(key)
-        return engine._disseminate_local(st, cfg, tx, tr, rc, k_push, k_pull, pl)
-
-    incoming, _ = one_delivery(
-        jax.random.key(17), state, transmit, transmitter, receptive, plan
-    )
-    fresh = None
-    if cfg.churn_join_prob > 0.0:
-        # a plausibly-dense fresh mask (the tail's churn-reset operand):
-        # Bernoulli(join_prob) over existing slots, like a real join draw
-        k_fresh = jax.random.key(23)
-        fresh = state.exists & (
-            jax.random.uniform(k_fresh, state.alive.shape)
-            < cfg.churn_join_prob
-        )
-
-    def fold(c, *arrays):
-        for a in arrays:
-            c = c ^ jnp.sum(a, dtype=jnp.int32)
-        return c
-
-    # every stage body receives its device arrays as slope_time OPERANDS —
-    # closure-captured arrays would become XLA constants and melt compile
-    # time into constant folding (see slope_time's docstring)
-    def t_delivery(i, c, st, tx, tr, rc, pl):
-        inc, msgs = one_delivery(
-            jax.random.fold_in(jax.random.key(1), i), st, tx, tr, rc, pl
-        )
-        return fold(c, inc, msgs)
-
-    def tail_body(impl):
-        def body(i, c, st, inc, rc, tx, fr):
-            seen, fwd, ir, rec = round_tail(
-                st.seen, st.forwarded, st.infected_round, st.recovered,
-                inc, rc, tx, fr, i,
-                forward_once=cfg.forward_once,
-                sir_recover_rounds=cfg.sir_recover_rounds, impl=impl,
-            )
-            return fold(c, seen, fwd, ir, rec)
-
-        return body
-
-    def t_liveness(i, c, st):
-        from tpu_gossip.kernels.liveness import detect_failures, emit_heartbeats
-
-        hb = emit_heartbeats(
-            st.last_hb, st.alive, st.silent, st.declared_dead,
-            i, cfg.hb_period_rounds,
-        )
-        hb, dead = detect_failures(
-            hb, st.alive, st.silent, st.declared_dead,
-            i, cfg.timeout_rounds, cfg.detect_period_rounds,
-        )
-        return fold(c, hb, dead)
-
-    def t_stats(i, c, st):
-        stats = engine._stats(st, i, None, growth, stream)
-        return fold(c, stats.msgs_sent, stats.n_infected, stats.n_alive) ^ (
-            stats.coverage > 0.5
-        ).astype(jnp.int32)
-
-    def t_rng(i, c):
-        keys = jax.random.split(jax.random.fold_in(jax.random.key(2), i), 5)
-        return fold(c, jax.random.key_data(keys)[..., 0].astype(jnp.int32))
-
-    def t_growth(i, c, st, gp):
-        from tpu_gossip.growth.engine import apply_growth
-
-        grown = apply_growth(
-            gp, jax.random.fold_in(st.rng, i), i,
-            jnp.zeros((), dtype=jnp.int32),
-            row_ptr=st.row_ptr, exists=st.exists, alive=st.alive,
-            silent=st.silent, last_hb=st.last_hb,
-            declared_dead=st.declared_dead, rewired=st.rewired,
-            rewire_targets=st.rewire_targets, join_round=st.join_round,
-            admitted_by=st.admitted_by, degree_credit=st.degree_credit,
-        )
-        return fold(c, grown["exists"], grown["join_round"],
-                    grown["degree_credit"])
-
-    def t_stream(i, c, st, sp):
-        from tpu_gossip.traffic.engine import apply_stream, slot_expiry
-
-        expired = slot_expiry(st.slot_lease, i, sp.ttl)
-        lease = jnp.where(expired, -1, st.slot_lease)
-        seen, infected_round, lease, stel = apply_stream(
-            sp, jax.random.fold_in(st.rng, i), i,
-            jnp.sum(expired, dtype=jnp.int32),
-            seen=st.seen, infected_round=st.infected_round,
-            slot_lease=lease, row_ptr=st.row_ptr, col_idx=st.col_idx,
-            exists=st.exists, alive=st.alive,
-            declared_dead=st.declared_dead,
-        )
-        return fold(c, seen, infected_round, lease, stel.injected)
-
-    def t_control(i, c, st, inc, cp):
-        from tpu_gossip.control.engine import apply_control, control_round
-
-        rctl = control_round(cp, st,
-                             want_needy=cfg.mode == "push_pull")
-        lvl, tgts, credit, ctel = apply_control(
-            cp, jax.random.fold_in(st.rng, i), i, rctl,
-            incoming=inc, seen_prev=st.seen, seen=st.seen | inc,
-            alive=st.alive, declared_dead=st.declared_dead,
-            exists=st.exists, rewired=st.rewired,
-            rewire_targets=st.rewire_targets,
-            degree_credit=st.degree_credit, row_ptr=st.row_ptr,
-            col_idx=st.col_idx, slot_lease=st.slot_lease,
-            rewire_slots=cfg.rewire_slots, fstats=None,
-        )
-        return fold(c, lvl, tgts, credit, ctel.fanout)
-
-    def t_transport(i, c, payload):
-        from tpu_gossip.dist.transport import (
-            compact_index, gather_compact, occupancy_counts,
-            scatter_compact,
-        )
-
-        _, b_probe, _, budget = transport_probe
-        occ = (payload != 0).any(-1)
-        counts = occupancy_counts(occ)
-        idx = compact_index(occ, budget)
-        back = scatter_compact(idx, gather_compact(payload, idx), b_probe)
-        return fold(c, counts, back)
-
-    def round_body(impl):
-        def body(i, s, pl, gp, sp, cp):
-            nxt, _ = engine.gossip_round(s, cfg, pl, tail=impl,
-                                         growth=gp, stream=sp,
-                                         control=cp)
-            return nxt
-
-        return body
-
-    zero = jnp.int32(0)
-    deliver_ops = (state, transmit, transmitter, receptive, plan)
-    tail_ops = (state, incoming, receptive, transmit, fresh)
-    stages: dict[str, float] = {}
-    stages["delivery"] = slope_time(
-        t_delivery, zero, n1, n2, reps, operands=deliver_ops
-    )
-    for impl in tails:
-        stages[f"tail[{impl}]"] = slope_time(
-            tail_body(impl), zero, n1, n2, reps, operands=tail_ops
-        )
-    stages["liveness"] = slope_time(
-        t_liveness, zero, n1, n2, reps, operands=(state,)
-    )
-    stages["stats"] = slope_time(t_stats, zero, n1, n2, reps, operands=(state,))
-    stages["rng"] = slope_time(t_rng, zero, n1, n2, reps)
-    # the compiled plans ride as OPERANDS like every other device input
-    # (this file's own rule: closure-captured arrays become XLA constants
-    # and melt compile time into constant folding — a CompiledStream's
-    # origin table is (n_real,) device data)
-    if growth is not None:
-        stages["growth"] = slope_time(
-            t_growth, zero, n1, n2, reps, operands=(state, growth)
-        )
-    if stream is not None:
-        stages["stream"] = slope_time(
-            t_stream, zero, n1, n2, reps, operands=(state, stream)
-        )
-    if control is not None:
-        stages["control"] = slope_time(
-            t_control, zero, n1, n2, reps, operands=(state, incoming, control)
-        )
-    if transport_probe is not None:
-        s_probe, b_probe, g_probe, _budget = transport_probe
-        # a plausibly-sparse synthetic payload (~1/8 occupancy — the
-        # compact lane's design point): nonzero words where the mask hits
-        k_probe = jax.random.key(29)
-        occ_mask = (
-            jax.random.uniform(k_probe, (s_probe, b_probe, 1)) < 0.125
-        )
-        payload = jnp.where(
-            occ_mask, jnp.int32(0x5A5A5A5A), jnp.int32(0)
-        ) | jnp.zeros((s_probe, b_probe, g_probe), dtype=jnp.int32)
-        stages["transport_compact"] = slope_time(
-            t_transport, zero, n1, n2, reps, operands=(payload,)
-        )
-    for impl in tails:
-        stages[f"full_round[{impl}]"] = slope_time(
-            round_body(impl), state, n1, n2, reps,
-            operands=(plan, growth, stream, control),
-        )
-    return stages
-
-
-def format_stage_table(stages: dict[str, float]) -> str:
-    """The stage dict as a markdown table (ms per round), in the profiler's
-    emission order — decomposition stages first, composed rounds last (the
-    docs/round_tail_profile.md row format)."""
-    lines = ["| stage | ms/round |", "|---|---|"]
-    for name, secs in stages.items():
-        ms = secs * 1e3
-        lines.append(f"| {name} | {ms:.3f} |")
-    return "\n".join(lines)
