@@ -237,6 +237,42 @@ def test_same_key_reused_across_states(pa_graph):
     assert int(fin.round) == 2
 
 
+def _buffer(x):
+    """The device buffer behind an array (a key's: its key data's)."""
+    if jax.dtypes.issubdtype(x.dtype, jax.dtypes.prng_key):
+        x = jax.random.key_data(x)
+    return x.unsafe_buffer_pointer()
+
+
+@pytest.mark.parametrize("graph_kind", ["host", "device"])
+@pytest.mark.parametrize("exists_kind", [None, "host", "device"])
+def test_init_swarm_leaves_own_their_buffers(pa_graph, matching, graph_kind,
+                                             exists_kind):
+    """The builder program forwards no input as an output: no leaf shares
+    a buffer with the caller's CSR, ``exists`` or key, and no two leaves
+    share one — what donating the state relies on."""
+    if graph_kind == "host":
+        g, n = pa_graph, N
+    else:
+        dg, _ = matching
+        g, n = dg.as_padded_graph(), dg.n_pad
+    mask = np.ones(n, dtype=bool)
+    mask[-1] = False
+    exists = {None: None, "host": mask,
+              "device": jax.numpy.asarray(mask)}[exists_kind]
+    key = jax.random.key(7)
+    cfg = SwarmConfig(n_peers=n, msg_slots=8, rewire_slots=2)
+    st = init_swarm(g, cfg, origins=[0, 1], key=key, exists=exists)
+    leaves = jax.tree_util.tree_leaves(st)
+    owned = [_buffer(x) for x in leaves]
+    assert len(set(owned)) == len(owned)
+    caller = [_buffer(key)] + [
+        _buffer(x) for x in (g.row_ptr, g.col_idx, exists)
+        if isinstance(x, jax.Array)
+    ]
+    assert not set(owned) & set(caller)
+
+
 def test_bench_swarm_donation_safe(pa_graph):
     """bench_swarm reps clone internally: the caller's state survives the
     benchmark, and the legacy zero-arg runner is rejected loudly."""

@@ -130,7 +130,8 @@ def test_fold_planes_scope():
 
 def test_init_swarm_runs_inside_its_host_span(overlays, monkeypatch):
     """``init_swarm`` enters a profiler annotation named after it and
-    builds the state inside it."""
+    launches the state's one builder program inside it, once a call — on
+    the builder's first call (a trace) and on a cached one alike."""
     import tpu_gossip.core.state as state_mod
 
     events = []
@@ -145,20 +146,19 @@ def test_init_swarm_runs_inside_its_host_span(overlays, monkeypatch):
         def __exit__(self, *exc):
             events.append(("exit", self.name))
 
-    built = []
-    real = state_mod.SwarmState
+    real = state_mod._fresh_state
 
     def record_build(*a, **kw):
-        built.append(len(events))
+        events.append(("build", None))
         return real(*a, **kw)
 
     # the annotation jax.profiler.annotate_function enters
     monkeypatch.setattr(jax._src.profiler, "TraceAnnotation", Recorder)
-    monkeypatch.setattr(state_mod, "SwarmState", record_build)
+    monkeypatch.setattr(state_mod, "_fresh_state", record_build)
     dg, _ = overlays["flood"]
-    _swarm(dg, "flood")
+    for _ in range(2):
+        _swarm(dg, "flood")
     # (JAX annotates some of its own calls the same way)
-    ours = [e for e in events if e[1] == "init_swarm"]
-    assert ours == [("enter", "init_swarm"), ("exit", "init_swarm")]
-    # the state was assembled between the two
-    assert events.index(ours[0]) < built[0] <= events.index(ours[1])
+    ours = [e for e in events if e[1] in ("init_swarm", None)]
+    assert ours == [("enter", "init_swarm"), ("build", None),
+                    ("exit", "init_swarm")] * 2

@@ -171,6 +171,111 @@ def test_config_validation():
         init_swarm(g, SwarmConfig(n_peers=49))
 
 
+def _eager_state(graph, config, *, key=None, origins=None, origin_slot=0,
+                 origin_slots=None, exists=None):
+    """The oracle: ``init_swarm``'s construction as one eager op per plane."""
+    from tpu_gossip.core.state import zero_suspicion
+
+    if key is None:
+        key = jax.random.key(0)
+    n, m = config.n_peers, config.msg_slots
+    seen = jnp.zeros((n, m), dtype=bool)
+    infected_round = jnp.full((n, m), -1, dtype=jnp.int16)
+    slot_lease = jnp.full((m,), -1, dtype=jnp.int16)
+    if origins is not None:
+        origins = jnp.asarray(origins)
+        if origin_slots is not None:
+            slots = jnp.asarray(np.asarray(origin_slots))
+        else:
+            slots = jnp.full(origins.shape, origin_slot)
+        seen = seen.at[origins, slots].set(True)
+        infected_round = infected_round.at[origins, slots].set(0)
+        slot_lease = slot_lease.at[slots].set(0)
+    if exists is None:
+        exists = jnp.ones((n,), dtype=bool)
+    exists = jnp.asarray(exists)
+    s = max(config.rewire_slots, 1)
+    return SwarmState(
+        row_ptr=jnp.asarray(graph.row_ptr, dtype=jnp.int32),
+        col_idx=jnp.asarray(graph.col_idx, dtype=jnp.int32),
+        seen=seen,
+        forwarded=jnp.zeros((n, m), dtype=bool),
+        infected_round=infected_round,
+        recovered=jnp.zeros((n, m), dtype=bool),
+        exists=exists,
+        alive=exists.copy(),
+        silent=jnp.zeros((n,), dtype=bool),
+        last_hb=jnp.zeros((n,), dtype=jnp.int16),
+        declared_dead=jnp.zeros((n,), dtype=bool),
+        rewired=jnp.zeros((n,), dtype=bool),
+        rewire_targets=jnp.zeros((n, s), dtype=jnp.int32),
+        fault_held=jnp.zeros((n, m), dtype=bool),
+        join_round=jnp.where(exists, 0, -1).astype(jnp.int16),
+        admitted_by=jnp.full((n,), -1, dtype=jnp.int32),
+        degree_credit=jnp.zeros((n,), dtype=jnp.int32),
+        slot_lease=slot_lease,
+        control_lvl=jnp.asarray(-1, dtype=jnp.int32),
+        pipe_buf=jnp.zeros((n, m), dtype=bool),
+        **zero_suspicion(n),
+        rng=key.copy(),
+        round=jnp.asarray(0, dtype=jnp.int32),
+    )
+
+
+@pytest.fixture(scope="module")
+def device_graph():
+    from tpu_gossip.core.matching_topology import matching_powerlaw_graph
+
+    dg, _ = matching_powerlaw_graph(300, gamma=2.5, fanout=1,
+                                    key=jax.random.key(0))
+    return dg
+
+
+# (graph, msg_slots, rewire_slots, exists given, key seed, init_swarm kwargs)
+BUILD_CASES = {
+    "no_origins": ("host", 16, 0, False, None, {}),
+    "origin_slot": ("host", 16, 0, True, 3,
+                    dict(origins=[0, 5, 9], origin_slot=3)),
+    "origin_slots": ("device", 16, 2, True, 4,
+                     dict(origins=np.array([1, 2, 3]),
+                          origin_slots=np.arange(3) * 7)),
+    "duplicate_origin": ("device", 1, 3, True, None,
+                         dict(origins=[4, 4, 2])),
+    "duplicate_origin_slots": ("host", 16, 0, False, 5,
+                               dict(origins=[4, 4, 8],
+                                    origin_slots=[2, 2, 15])),
+    "one_slot_int64_origins": ("host", 1, 0, False, 6,
+                               dict(origins=np.flatnonzero(np.arange(100)
+                                                           % 17 == 0))),
+    "device_no_origins": ("device", 16, 4, True, 7, {}),
+    "device_default_exists": ("device", 16, 0, False, 8,
+                              dict(origins=[0])),
+}
+
+
+@pytest.mark.parametrize("case", list(BUILD_CASES))
+def test_init_swarm_matches_eager_build(case, device_graph):
+    """The one-program build equals the eager one plane by plane: dtype,
+    shape and value, the PRNG key's data included."""
+    graph_kind, m, rewire, given, seed, kw = BUILD_CASES[case]
+    if graph_kind == "host":
+        g, exists = small_graph(100), np.arange(100) % 9 != 4
+    else:
+        g, exists = device_graph.as_padded_graph(), device_graph.exists
+    exists = exists if given else None
+    cfg = SwarmConfig(n_peers=g.n, msg_slots=m, rewire_slots=rewire)
+    key = None if seed is None else jax.random.key(seed)
+    got = init_swarm(g, cfg, key=key, exists=exists, **kw)
+    want = _eager_state(g, cfg, key=key, exists=exists, **kw)
+    for f in dataclasses.fields(SwarmState):
+        a, b = getattr(got, f.name), getattr(want, f.name)
+        assert a.dtype == b.dtype and a.shape == b.shape, f.name
+        if f.name == "rng":
+            a, b = jax.random.key_data(a), jax.random.key_data(b)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f.name)
+
+
 def test_init_swarm_origin_slots_multi_rumor():
     """origin_slots seeds one rumor per slot (the M>1 bench shape)."""
     import jax

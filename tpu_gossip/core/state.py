@@ -31,6 +31,7 @@ after 6 rounds (30 s) ≈ "3 missed heartbeats" per BASELINE config 2.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -780,6 +781,51 @@ def message_slots(
     return tuple(out)
 
 
+@functools.partial(jax.jit, static_argnames=("n", "m", "s"))
+def _fresh_state(row_ptr, col_idx, exists, key, origins, slots, *, n, m, s):
+    """The whole fresh :class:`SwarmState` as one XLA program.
+
+    ``origins``/``slots`` are int32 (zero-length: no origins, the scatters
+    write nothing); ``exists`` None means every row is a real peer. Every
+    output is a buffer of its own (computed, or an explicit copy of an
+    input): the round entry points donate the state, and a leaf that was
+    a caller's array (a DeviceGraph's CSR, a plan's ``exists`` mask, a
+    reused PRNG key) would be deleted with it."""
+    exists = jnp.ones((n,), dtype=bool) if exists is None else jnp.copy(exists)
+    # seeded slots hold round-0 "messages": under a streaming run
+    # (traffic/) their lease ages out like any injected message's;
+    # without one the table is carried untouched
+    slot_lease = jnp.full((m,), -1, dtype=jnp.int16).at[slots].set(0)
+    return SwarmState(
+        row_ptr=jnp.array(row_ptr, dtype=jnp.int32),
+        col_idx=jnp.array(col_idx, dtype=jnp.int32),
+        seen=jnp.zeros((n, m), dtype=bool).at[origins, slots].set(True),
+        forwarded=jnp.zeros((n, m), dtype=bool),
+        infected_round=jnp.full((n, m), -1, dtype=jnp.int16)
+        .at[origins, slots].set(0),
+        recovered=jnp.zeros((n, m), dtype=bool),
+        exists=exists,
+        # a SEPARATE buffer from exists — two leaves sharing one buffer
+        # would confuse the donation aliasing
+        alive=jnp.copy(exists),
+        silent=jnp.zeros((n,), dtype=bool),
+        last_hb=jnp.zeros((n,), dtype=jnp.int16),
+        declared_dead=jnp.zeros((n,), dtype=bool),
+        rewired=jnp.zeros((n,), dtype=bool),
+        rewire_targets=jnp.zeros((n, s), dtype=jnp.int32),
+        fault_held=jnp.zeros((n, m), dtype=bool),
+        # registry plane: existing rows are bootstrap members (join round
+        # 0, no admitting seed); non-existent rows are admittable capacity
+        **_zero_registry(exists),
+        slot_lease=slot_lease,
+        control_lvl=jnp.asarray(-1, dtype=jnp.int32),
+        pipe_buf=jnp.zeros((n, m), dtype=bool),
+        **zero_suspicion(n),
+        rng=jnp.copy(key),
+        round=jnp.asarray(0, dtype=jnp.int32),
+    )
+
+
 @jax.profiler.annotate_function
 def init_swarm(
     graph: Graph,
@@ -801,6 +847,12 @@ def init_swarm(
     nothing peer-sized crosses the host link. ``exists`` marks real peer
     slots (default all); non-existent slots (pads/sentinels) start dead.
 
+    The state is built by ONE jitted XLA program (``_fresh_state``): it
+    compiles once per (N, M, rewire width, origin count), and every later
+    call is a single launch. Every leaf is a
+    buffer of its own, never the caller's graph, ``exists`` or key, so the
+    donating round entry points cannot delete them.
+
     The call runs inside a host ``jax.profiler.TraceAnnotation`` named
     ``init_swarm``: in a profiler trace the span shares the device ops'
     clock, so the reset's dispatches can be laid against it.
@@ -810,73 +862,29 @@ def init_swarm(
     if key is None:
         key = jax.random.key(0)
     n, m = config.n_peers, config.msg_slots
-    seen = jnp.zeros((n, m), dtype=bool)
-    infected_round = jnp.full((n, m), -1, dtype=jnp.int16)
-    slot_lease = jnp.full((m,), -1, dtype=jnp.int16)
-    if origins is not None:
-        origins = jnp.asarray(origins)
+    if origins is None:
+        origins = slots = np.zeros((0,), dtype=np.int32)
+    else:
+        origins = np.asarray(origins)
         if origin_slots is not None:
-            slots_host = np.asarray(origin_slots)
-            if slots_host.shape != np.asarray(origins).shape:
+            slots = np.asarray(origin_slots)
+            if slots.shape != origins.shape:
                 raise ValueError(
-                    f"origin_slots shape {slots_host.shape} != origins shape"
-                    f" {np.asarray(origins).shape}"
+                    f"origin_slots shape {slots.shape} != origins shape"
+                    f" {origins.shape}"
                 )
-            if slots_host.size and (slots_host.min() < 0 or slots_host.max() >= m):
+            if slots.size and (slots.min() < 0 or slots.max() >= m):
                 raise ValueError(
                     f"origin_slots must lie in [0, msg_slots={m}); got "
-                    f"[{slots_host.min()}, {slots_host.max()}]"
+                    f"[{slots.min()}, {slots.max()}]"
                 )
-            slots = jnp.asarray(slots_host)
+            slots = slots.astype(np.int32)
         else:
-            slots = jnp.full(origins.shape, origin_slot)
-        seen = seen.at[origins, slots].set(True)
-        infected_round = infected_round.at[origins, slots].set(0)
-        # seeded slots hold round-0 "messages": under a streaming run
-        # (traffic/) their lease ages out like any injected message's;
-        # without one the table is carried untouched
-        slot_lease = slot_lease.at[slots].set(0)
-    if exists is None:
-        exists = jnp.ones((n,), dtype=bool)
-
-    def owned(x, dtype=None):
-        """The state must OWN every leaf: the round entry points donate the
-        state pytree, and a leaf aliasing a caller array (a DeviceGraph's
-        CSR, a plan's ``exists`` mask, a reused PRNG key) would delete the
-        caller's array with it. ``jnp.asarray`` on an already-device array
-        of the right dtype is a no-copy identity — force the copy exactly
-        then; host arrays were copied to device by asarray anyway."""
-        arr = jnp.asarray(x) if dtype is None else jnp.asarray(x, dtype=dtype)
-        return arr.copy() if arr is x else arr
-
-    exists = owned(exists)
-    s = max(config.rewire_slots, 1)
-    return SwarmState(
-        row_ptr=owned(graph.row_ptr, dtype=jnp.int32),
-        col_idx=owned(graph.col_idx, dtype=jnp.int32),
-        seen=seen,
-        forwarded=jnp.zeros((n, m), dtype=bool),
-        infected_round=infected_round,
-        recovered=jnp.zeros((n, m), dtype=bool),
-        exists=exists,
-        # a SEPARATE buffer from exists — two leaves sharing one buffer
-        # would confuse the donation aliasing
-        alive=exists.copy(),
-        silent=jnp.zeros((n,), dtype=bool),
-        last_hb=jnp.zeros((n,), dtype=jnp.int16),
-        declared_dead=jnp.zeros((n,), dtype=bool),
-        rewired=jnp.zeros((n,), dtype=bool),
-        rewire_targets=jnp.zeros((n, s), dtype=jnp.int32),
-        fault_held=jnp.zeros((n, m), dtype=bool),
-        # registry plane: existing rows are bootstrap members (join round
-        # 0, no admitting seed); non-existent rows are admittable capacity
-        join_round=jnp.where(exists, 0, -1).astype(jnp.int16),
-        admitted_by=jnp.full((n,), -1, dtype=jnp.int32),
-        degree_credit=jnp.zeros((n,), dtype=jnp.int32),
-        slot_lease=slot_lease,
-        control_lvl=jnp.asarray(-1, dtype=jnp.int32),
-        pipe_buf=jnp.zeros((n, m), dtype=bool),
-        **zero_suspicion(n),
-        rng=key.copy(),  # keys are always jax arrays; same ownership rule
-        round=jnp.asarray(0, dtype=jnp.int32),
+            slots = np.full(origins.shape, origin_slot, dtype=np.int32)
+        # one dtype whatever the caller passed (np.flatnonzero gives
+        # int64): one executable per origin count
+        origins = origins.astype(np.int32)
+    return _fresh_state(
+        graph.row_ptr, graph.col_idx, exists, key, origins, slots,
+        n=n, m=m, s=max(config.rewire_slots, 1),
     )
