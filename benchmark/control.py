@@ -114,7 +114,7 @@ def main(argv: list[str] | None = None) -> int:
     opts = ap.parse_args(argv)
     import jax
 
-    from benchmark import check, reference
+    from benchmark import check
     from benchmark.harness import Swarm
     from benchmark.run import device_error, run_cell
     from benchmark.spec import load_cell
@@ -143,9 +143,8 @@ def main(argv: list[str] | None = None) -> int:
         swarm.release()
         k = int(cell.traffic["checked_broadcasts"])
         broadcasts, sample = control_answers(swarm, rp, ci, k)
-        law = reference.law_degrees(cell.peers, swarm.args.gamma)
-        numbers, detail = check.compare(swarm.args, rp, ci, cell.peers,
-                                        broadcasts, sample, seed, law)
+        numbers, detail = swarm.compare(swarm.args, rp, ci, cell.peers,
+                                        broadcasts, sample, seed, swarm.law())
         correct, shown = check.verdict(numbers, cell.config["check"])
         print(json.dumps({"workload": cell.name, "seed": seed,
                           "control": opts.control,

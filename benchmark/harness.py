@@ -1,32 +1,29 @@
 """Set-up and the measured window: the program's own run-to-coverage path.
 
 Set-up reads the configuration's ``run_sim`` arguments through
-``run_sim.build_parser()``, builds the overlay and its plan once with
-``matching_powerlaw_graph`` exactly as ``run_sim._run`` calls it, and warms
-the broadcast path. One broadcast in the window is ``init_swarm`` with fresh
-origins, ``run_until_coverage`` with the plan and the configured tail, and
-a host fetch of the final coverage and round. Broadcasts run back to back
-until the window's seconds are spent.
+``run_sim.build_parser()``, picks the engine of ``benchmark/engines/`` that
+drives the path they select, has it build the overlay and its plan once, as
+``run_sim`` does, and warms the broadcast path. One broadcast in the window
+is the engine's reset with fresh origins, its run to coverage, and a host
+fetch of the final coverage and round. Broadcasts run back to back until the
+window's seconds are spent. The origins, the keys, the spans and the timers
+of a broadcast are the harness's, the same for every engine.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
+import importlib
+import pkgutil
 import time
 
 import numpy as np
 
+from benchmark import check, engines
 from benchmark.reference import bfs
 from benchmark.spec import Cell
 
-# run_sim options the broadcast loop honours; any other option set away
-# from its default names a path this harness does not drive
-HONOURED = frozenset({
-    "graph", "mode", "fanout", "slots", "gamma", "target", "max_rounds",
-    "forward_once", "tail",
-})
 WARMUP_BROADCASTS = 2
 
 
@@ -56,7 +53,10 @@ class CompileClock:
 
 
 def sim_args(cell: Cell, seed: int):
-    """The configuration's run_sim arguments at the cell's swarm size."""
+    """The configuration's run_sim arguments at the cell's swarm size, and
+    the one engine module of ``benchmark/engines/`` that drives them: its
+    ``accepts`` holds and it honours every option set away from its
+    default."""
     from tpu_gossip.cli.run_sim import build_parser
 
     argv = list(cell.config["run_sim"])
@@ -67,12 +67,17 @@ def sim_args(cell: Cell, seed: int):
     args = parser.parse_args(argv + ["--peers", str(cell.peers),
                                      "--seed", str(seed)])
     defaults = vars(parser.parse_args([]))
-    odd = sorted(k for k, v in vars(args).items()
-                 if k not in HONOURED | {"peers", "seed"} and v != defaults[k])
-    if odd or args.graph != "matching":
-        raise ValueError(f"the broadcast harness drives the local matching "
-                         f"path only; unsupported run_sim options: {odd}")
-    return args
+    odd = {k for k, v in vars(args).items()
+           if k not in {"peers", "seed"} and v != defaults[k]}
+    found = []
+    for info in pkgutil.iter_modules(engines.__path__):
+        engine = importlib.import_module(f"{engines.__name__}.{info.name}")
+        if engine.accepts(args) and odd <= engine.HONOURED:
+            found.append(engine)
+    if len(found) != 1:
+        raise ValueError(f"{len(found)} engines of benchmark/engines/ drive "
+                         f"these run_sim options, not one: {sorted(odd)}")
+    return args, found[0]
 
 
 def broadcast_origins(seed: int, index: int, pool: np.ndarray, rumors: int,
@@ -96,11 +101,6 @@ def origin_pool(law: str, rp: np.ndarray, ci: np.ndarray, n: int):
     return np.flatnonzero(bfs(rp, ci, n, [n - 1]) >= 0)
 
 
-def _readout(state, rumors: int):
-    """Final coverage, round and the rumor slots' infection rounds."""
-    return state.coverage(0), state.round, state.infected_round[:, :rumors]
-
-
 @dataclasses.dataclass
 class Broadcast:
     index: int
@@ -110,51 +110,36 @@ class Broadcast:
 
 
 class Swarm:
-    """A built cell: overlay, plan, configuration and the broadcast loop.
+    """A built cell: its engine's overlay and plan, and the broadcast loop.
 
-    ``run`` is the program's ``run_until_coverage``; the benchmark's own
-    tests put a broken one in its place to see the check fail. ``build``
-    stands in for ``matching_powerlaw_graph`` in the control only
-    (benchmark/control.py).
+    ``run`` is the engine's run to coverage; the benchmark's own tests put a
+    broken one in its place to see the check fail. ``build`` stands in for
+    the program's overlay build in the control and the benchmark's tests
+    (benchmark/control.py). ``compare`` is the engine module's own
+    comparison, or ``check.compare``.
     """
 
     def __init__(self, cell: Cell, seed: int, annotate=None, build=None):
         import jax
 
-        from tpu_gossip.core.matching_topology import matching_powerlaw_graph
-        from tpu_gossip.core.state import SwarmConfig, init_swarm
-        from tpu_gossip.sim.engine import run_until_coverage
-
         self.cell, self.seed = cell, seed
-        self.args = args = sim_args(cell, seed)
+        self.args, module = sim_args(cell, seed)
         self.rumors = int(cell.traffic["rumors_per_broadcast"])
-        if not 1 <= self.rumors <= args.slots:
+        if not 1 <= self.rumors <= self.args.slots:
             raise ValueError("rumors_per_broadcast must lie in [1, slots]")
         self.annotate = annotate or (lambda name: contextlib.nullcontext())
-        self.init_swarm, self.run = init_swarm, run_until_coverage
-        self._readout = jax.jit(functools.partial(_readout,
-                                                  rumors=self.rumors))
+        self.compare = getattr(module, "compare", check.compare)
+        self.engine = module.Engine(self.args, self.rumors, build)
+        self.run = self.engine.run
         self._key = jax.random.key
 
         t0 = time.perf_counter()
-        dgraph, self.plan = (build or matching_powerlaw_graph)(
-            args.peers, gamma=args.gamma,
-            fanout=None if args.mode == "flood" else args.fanout,
-            key=jax.random.key(args.seed),
-        )
-        self.graph, self.exists = dgraph.as_padded_graph(), dgraph.exists
-        int(self.graph.row_ptr[-1])
+        self.engine.build()
         self.t_built = time.perf_counter()
         self.graph_build_s = self.t_built - t0
-        n = cell.peers
-        rp, ci = (np.asarray(a) for a in (self.graph.row_ptr,
-                                          self.graph.col_idx))
-        self.csr = rp[: n + 1].astype(np.int64), ci[: rp[n]].astype(np.int64)
-        self.pool = origin_pool(cell.traffic["origin_law"], *self.csr, n)
-        self.cfg = SwarmConfig(
-            n_peers=self.graph.n, msg_slots=args.slots, fanout=args.fanout,
-            mode=args.mode, forward_once=args.forward_once,
-        )
+        self.csr = self.engine.overlay()
+        self.pool = origin_pool(cell.traffic["origin_law"], *self.csr,
+                                cell.peers)
         self.reset_s = 0.0
         self.loop_s = 0.0
 
@@ -165,16 +150,11 @@ class Swarm:
         origins, kseed = broadcast_origins(self.seed, index, self.pool,
                                            self.rumors, stream)
         with self.annotate("reset"):
-            state = self.init_swarm(
-                self.graph, self.cfg, key=self._key(kseed), origins=origins,
-                origin_slots=np.arange(self.rumors), exists=self.exists,
-            )
+            state = self.engine.reset(origins, self._key(kseed))
         t1 = time.perf_counter()
         with self.annotate("dispatch"):
-            fin = self.run(state, self.cfg, self.args.target,
-                           self.args.max_rounds, plan=self.plan,
-                           tail=self.args.tail)
-            cov, rnd, held = self._readout(fin)
+            fin = self.run(state)
+            cov, rnd, held = self.engine.readout(fin)
         with self.annotate("fetch"):
             cov, rnd = float(cov), int(rnd)
         t2 = time.perf_counter()
@@ -187,9 +167,13 @@ class Swarm:
         fetched to the host in set-up."""
         return self.csr
 
+    def law(self) -> np.ndarray:
+        """The degree law the reference holds the overlay to."""
+        return self.engine.law()
+
     def release(self) -> None:
         """Drop the program's device state (graph, plan)."""
-        self.graph = self.plan = self.exists = None
+        self.engine.release()
 
 
 @dataclasses.dataclass

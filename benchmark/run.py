@@ -2,7 +2,8 @@
 
     python -m benchmark.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up builds the cell (benchmark/harness.py) and warms its broadcast path;
+Set-up builds the cell with the engine its configuration selects
+(benchmark/harness.py, benchmark/engines/) and warms its broadcast path;
 the window runs broadcasts back to back for ``--seconds``; then the window's
 answers are compared with the plain reference (benchmark/check.py). The last
 line of standard output is one JSON object: ``correct``, ``attempted``,
@@ -63,11 +64,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
              t_start: float, breaker=None, build=None) -> tuple[dict, list]:
     """Set-up, window and check of one cell: (result line, check lines).
     ``breaker`` (the benchmark's tests only) gets the built swarm first;
-    ``build`` (the control only) builds the overlay in the program's place."""
+    ``build`` (the control and the benchmark's tests) builds the overlay in
+    the program's place."""
     import jax
     import numpy as np
 
-    from benchmark import check, reference
+    from benchmark import check
     from benchmark.harness import CompileClock, Swarm, run_window, warm_up
     from benchmark.spec import load_peaks
     from benchmark.trace import Tracer, busy_s, idle_gaps, op_seconds, top
@@ -105,9 +107,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     rp, ci = swarm.overlay()
     swarm.release()
     t1 = time.perf_counter()
-    law = reference.law_degrees(cell.peers, swarm.args.gamma)
-    numbers, detail = check.compare(swarm.args, rp, ci, cell.peers,
-                                     win.broadcasts, sample, seed, law)
+    numbers, detail = swarm.compare(swarm.args, rp, ci, cell.peers,
+                                    win.broadcasts, sample, seed, swarm.law())
     detail.update(fetch_s=t1 - t0, compare_s=time.perf_counter() - t1)
     correct, shown = check.verdict(numbers, cell.config["check"])
 

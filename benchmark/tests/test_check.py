@@ -6,14 +6,15 @@ the control and each fault the cells can have are refused."""
 import dataclasses
 import subprocess
 import sys
+import textwrap
 import time
 
 import numpy as np
 import pytest
 
-from benchmark import check, reference
+from benchmark import check, engines
 from benchmark.control import coarse_build, control_answers
-from benchmark.harness import Swarm
+from benchmark.harness import Swarm, sim_args
 from benchmark.run import run_cell
 from benchmark.spec import ROOT, load_cell
 
@@ -54,8 +55,8 @@ def test_the_control_is_refused(name):
     swarm.release()
     k = int(cell.traffic["checked_broadcasts"])
     broadcasts, sample = control_answers(swarm, rp, ci, k)
-    numbers, _ = check.compare(swarm.args, rp, ci, PEERS, broadcasts, sample,
-                               SEED, reference.law_degrees(PEERS, 2.5))
+    numbers, _ = swarm.compare(swarm.args, rp, ci, PEERS, broadcasts, sample,
+                               SEED, swarm.law())
     correct, shown = check.verdict(numbers, cell.config["check"])
     assert not correct
     assert shown["faults"]["value"] > 0
@@ -107,6 +108,49 @@ def test_a_broken_timed_path_is_refused(name, fault):
     result = run(name, fault)
     assert not result["correct"]
     assert result["check"]["faults"]["value"] > 0
+
+
+@pytest.mark.parametrize("name", ["flood_1m", "pushpull_1m", "pushpull_10m"])
+def test_the_configuration_picks_its_engine(name):
+    args, module = sim_args(load_cell(name), SEED)
+    assert module.__name__ == "benchmark.engines.local"
+    assert args.seed == SEED and args.peers == load_cell(name).peers
+
+
+@pytest.mark.parametrize("extra", [["--churn-leave", "0.01"],
+                                   ["--shard", "--builder", "dist"],
+                                   ["--silent-frac", "0.1"]])
+def test_an_option_no_engine_honours_is_refused(extra):
+    """Churn, the sharded path and silent peers: no engine drives them."""
+    cell = load_cell("pushpull_1m")
+    cell.config["run_sim"] = cell.config["run_sim"] + extra
+    with pytest.raises(ValueError, match="0 engines"):
+        sim_args(cell, SEED)
+
+
+def test_an_engine_arrives_as_a_file(tmp_path, monkeypatch):
+    """A module dropped beside the others is found by listing the package
+    and takes the options it honours; where two engines accept one
+    configuration, the harness refuses it."""
+    (tmp_path / "churning.py").write_text(textwrap.dedent("""
+        from benchmark.engines.local import HONOURED as LOCAL
+
+        HONOURED = LOCAL | {"churn_leave"}
+
+        def accepts(args):
+            return args.graph == "matching"
+    """))
+    monkeypatch.setattr(engines, "__path__", [*engines.__path__,
+                                              str(tmp_path)])
+    cell = load_cell("pushpull_1m")
+    cell.config["run_sim"] = cell.config["run_sim"] + ["--churn-leave", "0.01"]
+    try:
+        _, module = sim_args(cell, SEED)
+        assert module.__name__ == "benchmark.engines.churning"
+        with pytest.raises(ValueError, match="2 engines"):
+            sim_args(load_cell("pushpull_1m"), SEED)
+    finally:
+        sys.modules.pop("benchmark.engines.churning", None)
 
 
 def test_no_tpu_no_result():
