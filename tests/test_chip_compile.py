@@ -11,6 +11,7 @@ the compiles (a chip executable written there cannot be read back here).
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,14 +73,25 @@ def test_interpret_follows_the_target_device(topo):
         assert interpret_default() is True
 
 
-@pytest.mark.parametrize("dtype", [jnp.int32, jnp.uint8])
-def test_lane_shuffle(sds, dtype):
-    """int32 words (local round) and uint8 words (sharded round)."""
+@pytest.mark.parametrize("dtype, rows", [
+    pytest.param(jnp.int32, 65536 + 672, id="int32"),
+    pytest.param(jnp.uint8, 65536 + 672, id="uint8"),
+    # the 10M plan's R = 437,568 slot rows, 1,344 past the last whole block
+    pytest.param(jnp.int32, 437_568, id="int32-10m"),
+])
+def test_lane_shuffle(sds, dtype, rows):
+    """int32 words (local round) and uint8 words (sharded round), rows
+    that end in a partial block: one kernel call over every row, with no
+    slice of the operand before it and no concatenate after it."""
     from tpu_gossip.kernels.permute import lane_shuffle
 
-    x = sds((65536 + 672, 128), dtype)  # a full grid + a remainder block
-    idx = sds((65536 + 672, 128), jnp.int8)
-    _compile(lambda a, b: lane_shuffle(a, b, interpret=False), x, idx)
+    x = sds((rows, 128), dtype)
+    idx = sds((rows, 128), jnp.int8)
+    text = _compile(lambda a, b: lane_shuffle(a, b, interpret=False), x, idx)
+    ops = re.findall(r"^\s*(?:ROOT )?%(\S+) = \S+ ([\w-]+)\(", text, re.M)
+    assert [op for _, op in ops if op != "parameter"] == ["custom-call"]
+    # the name a device trace gives the kernel, which shuffle_roofline reads
+    assert [name for name, op in ops if op == "custom-call"] == ["lane_shuffle.1"]
 
 
 def test_fold_planes(sds):

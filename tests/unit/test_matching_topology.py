@@ -25,15 +25,41 @@ from tpu_gossip.kernels.permute import (
 )
 
 
-def test_lane_shuffle_matches_numpy():
+# (rows, word dtype, table dtype): fewer rows than one block, whole blocks,
+# partial last blocks with the 1M (416) and 10M (1,344) plans' remainders,
+# and int32 tables at a row count that int8 tables cannot take
+SHUFFLE_CASES = [
+    (rows, dtype, np.int8)
+    for rows in (32, BLOCK_ROWS, BLOCK_ROWS + 416, 3 * BLOCK_ROWS + 1344)
+    for dtype in (np.int32, np.uint8)
+] + [(BLOCK_ROWS + 8, np.int32, np.int32)]
+
+
+@pytest.mark.parametrize("rows, dtype, table_dtype", SHUFFLE_CASES)
+def test_lane_shuffle_matches_numpy(rows, dtype, table_dtype):
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.integers(0, 2**31, (BLOCK_ROWS, 128), dtype=np.int32))
-    idx = jnp.asarray(rng.integers(0, 128, (BLOCK_ROWS, 128), dtype=np.int8))
+    x = jnp.asarray(rng.integers(0, np.iinfo(dtype).max, (rows, 128), dtype=dtype))
+    idx = jnp.asarray(rng.integers(0, 128, (rows, 128), dtype=table_dtype))
     out = lane_shuffle(x, idx)
     ref = np.take_along_axis(
         np.asarray(x), np.asarray(idx).astype(np.int64), axis=1
     )
+    assert out.dtype == x.dtype
     np.testing.assert_array_equal(np.asarray(out), ref)
+
+
+def test_lane_shuffle_is_one_call():
+    """A partial last block rides the same call: no slice of ``x`` before
+    it and no concatenate after it."""
+    rows = 3 * BLOCK_ROWS + 1344
+    x = jax.ShapeDtypeStruct((rows, 128), jnp.int32)
+    idx = jax.ShapeDtypeStruct((rows, 128), jnp.int8)
+    inner = jax.make_jaxpr(lane_shuffle)(x, idx).eqns[0].params["jaxpr"]
+    prims = [e.primitive.name for e in inner.eqns]
+    assert prims == ["pallas_call"]
+    (call,) = inner.eqns
+    assert call.params["grid_mapping"].grid == (4,)
+    assert call.outvars[0].aval.shape == (rows, 128)
 
 
 def test_transpose_pass_roundtrip():
@@ -44,15 +70,23 @@ def test_transpose_pass_roundtrip():
     )
 
 
-def test_inverse_tables_invert():
+def _shuffle_then_invert(rows):
     rng = np.random.default_rng(2)
-    perm = np.stack([rng.permutation(128) for _ in range(BLOCK_ROWS)]).astype(
+    perm = np.stack([rng.permutation(128) for _ in range(rows)]).astype(
         np.int8
     )
-    x = jnp.asarray(rng.integers(0, 2**31, (BLOCK_ROWS, 128), dtype=np.int32))
+    x = jnp.asarray(rng.integers(0, 2**31, (rows, 128), dtype=np.int32))
     idx = jnp.asarray(perm)
     out = lane_shuffle(lane_shuffle(x, idx), inverse_tables(idx))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+
+
+def test_inverse_tables_invert():
+    _shuffle_then_invert(BLOCK_ROWS)
+
+
+def test_inverse_tables_invert_past_a_partial_block():
+    _shuffle_then_invert(BLOCK_ROWS + 416)
 
 
 def _small_plan(n=3000, fanout=1, key=0):

@@ -24,8 +24,8 @@ per-socket send loop (reference Peer.py:395-408) becomes expand -> permute
 -> reduce, all at streaming rates.
 
 Row count is only required to be a multiple of 8 (one sublane tile): a
-non-multiple of :data:`BLOCK_ROWS` is handled as one full-grid call plus a
-single remainder block, so the stub array can hug the real stub count —
+non-multiple of :data:`BLOCK_ROWS` runs as the same single call, its last
+grid block partial, so the stub array can hug the real stub count —
 padding slots pair with real stubs and erase them, so the dead tail must
 stay tiny (core/matching_topology.py sizes it at <= 1023 slots).
 """
@@ -52,31 +52,20 @@ __all__ = [
     "fold_planes",
 ]
 
-BLOCK_ROWS = 2048  # rows per Pallas grid step; R must be a multiple
+BLOCK_ROWS = 2048  # rows per Pallas grid step; the last step may be partial
 
 
-def _shuffle_kernel(x_ref, idx_ref, o_ref):
+def _shuffle_kernel(x_ref, idx_ref, o_ref, *, wrap):
     # the chip's lane gather moves 32-bit data only: narrow payloads (the
     # sharded engine's uint8 words) widen in VMEM and narrow on the store
-    x = x_ref[:]
-    out = jnp.take_along_axis(
-        x.astype(jnp.int32), idx_ref[:].astype(jnp.int32), axis=1  # graftlint: disable=mem-widening-cast -- per-block VMEM window: the stored words keep their dtype
-    )
-    o_ref[:] = out.astype(o_ref.dtype)
-
-
-def _shuffle_call(x, idx, rows, interpret):
-    return pl.pallas_call(
-        _shuffle_kernel,
-        grid=(x.shape[0] // rows,),
-        in_specs=[
-            pl.BlockSpec((rows, 128), lambda j: (j, 0)),
-            pl.BlockSpec((rows, 128), lambda j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows, 128), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
-    )(x, idx)
+    x = x_ref[:].astype(jnp.int32)  # graftlint: disable=mem-widening-cast -- per-block VMEM window: the stored words keep their dtype
+    idx = idx_ref[:].astype(jnp.int32)  # graftlint: disable=mem-widening-cast -- per-block VMEM window: the stored words keep their dtype
+    if wrap:
+        # a partial last block reads rows past the array from whatever the
+        # buffer holds; their stores are dropped, but their lanes must stay
+        # in range
+        idx = idx & 127
+    o_ref[:] = jnp.take_along_axis(x, idx, axis=1).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -86,11 +75,12 @@ def lane_shuffle(
 ) -> jax.Array:
     """out[r, l] = x[r, idx[r, l]] — per-row 128-lane shuffle, Pallas.
 
-    ``x`` (R, 128) int32, ``idx`` (R, 128) int32 with values in [0, 128);
-    R must be a multiple of 8. Full :data:`BLOCK_ROWS` blocks go through one
-    grid; a remainder tail (< BLOCK_ROWS rows) rides a second single-block
-    call. Runs at VPU rate (~188 G elem/s measured) — the pass the whole
-    permutation pipeline is built from.
+    ``x`` (R, 128) int32 or narrower, ``idx`` (R, 128) int32 or int8 with
+    values in [0, 128); R must be a multiple of 8. One call over every row:
+    a grid of :data:`BLOCK_ROWS`-row blocks whose last block is partial when
+    R is not a multiple of it (the shuffle is row-local, so rows past R are
+    never written back). Runs at VPU rate (~188 G elem/s measured) — the
+    pass the whole permutation pipeline is built from.
     """
     if interpret is None:
         interpret = interpret_default()
@@ -101,13 +91,16 @@ def lane_shuffle(
         # int8 sublane tiling is (32, 128); narrow tables require 32-row
         # granularity (matching_topology sizes large plans that way)
         raise ValueError(f"int8 index tables need rows % 32 == 0, got {r}")
-    r0 = (r // BLOCK_ROWS) * BLOCK_ROWS
-    parts = []
-    if r0:
-        parts.append(_shuffle_call(x[:r0], idx[:r0], BLOCK_ROWS, interpret))
-    if r - r0:
-        parts.append(_shuffle_call(x[r0:], idx[r0:], r - r0, interpret))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    rows = min(r, BLOCK_ROWS)
+    spec = pl.BlockSpec((rows, 128), lambda j: (j, 0))
+    return pl.pallas_call(
+        functools.partial(_shuffle_kernel, wrap=r % rows != 0),
+        grid=(pl.cdiv(r, rows),),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=interpret,
+    )(x, idx)
 
 
 def transpose_pass(x: jax.Array) -> jax.Array:
