@@ -1,5 +1,5 @@
 """CLI contract: exit codes, JSON output, baseline interplay — the exact
-interface CI and bench.py depend on."""
+interface CI depends on."""
 
 import json
 from pathlib import Path

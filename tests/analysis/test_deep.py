@@ -394,7 +394,7 @@ def test_ast_donation_idioms_clean():
 
 
 def test_ast_pass_covers_the_real_scope():
-    """The live callers (cli/run_sim.py, bench.py, sim/, dist/) are clean
+    """The live callers (cli/run_sim.py, sim/, dist/) are clean
     against the real donating entry points — the enforcement half of the
     pass, with tracing off (pure AST)."""
     from tpu_gossip.analysis.deep import run_deep

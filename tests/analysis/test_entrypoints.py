@@ -43,7 +43,7 @@ def test_matrix_structural_coverage():
                   "control", "scenario+growth+stream+control",
                   "adversary", "scenario+growth+stream+control+adversary"):
         assert f"local[xla,{extra}]" in names
-    for tail in ("reference", "fused", "pallas"):
+    for tail in ("reference", "fused", "packed"):
         assert f"local[xla,tail={tail}]" in names
     assert "local[matching,scenario]" in names
     assert "local[matching,growth]" in names and "local[pallas,growth]" in names

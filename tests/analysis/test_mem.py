@@ -443,7 +443,7 @@ def test_round_cap_saturates_narrow_plane_writes():
         jnp.asarray(ROUND_CAP + 100, jnp.int32), 1,
     )
     assert str(hb2.dtype) == "int16" and (np.asarray(hb2) == ROUND_CAP).all()
-    for impl in ("fused", "reference", "pallas"):
+    for impl in ("fused", "reference", "packed"):
         _, _, ir2, _ = round_tail(
             jnp.zeros((4, 2), bool), jnp.zeros((4, 2), bool),
             jnp.full((4, 2), -1, jnp.int16), jnp.zeros((4, 2), bool),

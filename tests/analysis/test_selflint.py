@@ -1,7 +1,7 @@
 """Self-lint: the repo's own tree is clean modulo the committed baseline.
 
-This is the enforcement test — a new violation anywhere in tpu_gossip/ or
-bench.py fails HERE (and in CI via `python -m tpu_gossip.analysis`)
+This is the enforcement test — a new violation anywhere in tpu_gossip/ fails
+HERE (and in CI via `python -m tpu_gossip.analysis`)
 before it can land. Pragma hygiene is asserted alongside: every pragma in
 the tree carries a reason.
 """

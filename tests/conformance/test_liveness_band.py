@@ -1,6 +1,5 @@
 """Conformance: simulated failure-detection latency pinned inside the
-reference's wall-clock band (SURVEY §2.5), as a CI test rather than only a
-bench entry (BENCH_DETAIL.json ``liveness_1k``).
+reference's wall-clock band (SURVEY §2.5), as a CI test.
 
 The reference's constants — 15 s heartbeats, 30 s stale threshold, 10 s
 detector sweep, 2 s ping grace — bound worst-case silent-peer detection at

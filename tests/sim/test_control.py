@@ -160,8 +160,8 @@ def test_controlled_run_saves_messages_at_coverage():
     """The headline mechanism at test scale: AIMD narrowing + the mix
     drop the message bill at equal-or-better rounds-to-coverage. The
     margin GROWS with scale (the saturated late phase dominates the bill
-    — ~10% at 2k, ~26% at 1M); the headline-scale figure is bench.py's
-    ``control_1m``, this pins the mechanism and the direction."""
+    — ~10% at 2k, ~26% at 1M); this pins the mechanism and the
+    direction."""
     _, cfg, st = _pa_state(n=2000, mode="push_pull", msg_slots=4)
     ctl = compile_control(target_ratio=0.99, fanout=3, lo=1, hi=6)
     _, s0 = simulate(clone_state(st), cfg, 25)

@@ -4,7 +4,7 @@ Two contracts under test:
 
 1. **Tail bit-identity** — kernels/round_tail.py states the post-delivery
    slot passes three ways (reference multi-pass oracle, fused single
-   traversal, Pallas single launch); every implementation must produce the
+   traversal, packed word tail); every implementation must produce the
    IDENTICAL state trajectory on every engine (xla / staircase-pallas /
    matching) in every mode, churn and SIR included. Integer ops only, so
    equality is exact — and transitively the local↔sharded bit-identity
@@ -99,7 +99,7 @@ def _assert_identical(a, b, label):
         )
 
 
-def _run_tails(state, cfg, plan, rounds=4, tails=("fused", "reference", "pallas")):
+def _run_tails(state, cfg, plan, rounds=4, tails=("fused", "reference")):
     outs = {}
     for tail in tails:
         s = clone_state(state)
@@ -113,16 +113,13 @@ def _run_tails(state, cfg, plan, rounds=4, tails=("fused", "reference", "pallas"
 
 @pytest.mark.parametrize("mode,extra", XLA_ENGINE_GRID, ids=MODE_IDS)
 def test_tail_bit_identity_xla_engine(pa_graph, mode, extra):
-    # the full five-impl oracle sweep rides the XLA engine: the word-level
-    # packed tails must land the identical trajectory as the bool oracle
+    # the full three-impl oracle sweep rides the XLA engine: the word-level
+    # packed tail must land the identical trajectory as the bool oracle
     # in every mode (SIR, churn fresh masks, forward-once latch included)
     cfg = SwarmConfig(n_peers=N, msg_slots=8, fanout=2, mode=mode, **extra)
     st = init_swarm(pa_graph, cfg, origins=[0, 3], key=jax.random.key(7))
-    outs = _run_tails(
-        st, cfg, None,
-        tails=("fused", "reference", "pallas", "packed", "packed_pallas"),
-    )
-    for tail in ("reference", "pallas", "packed", "packed_pallas"):
+    outs = _run_tails(st, cfg, None, tails=("fused", "reference", "packed"))
+    for tail in ("reference", "packed"):
         _assert_identical(outs["fused"][0], outs[tail][0], f"xla/{tail}")
         for sa, sb in zip(outs["fused"][1], outs[tail][1]):
             assert int(sa.msgs_sent) == int(sb.msgs_sent)
@@ -138,8 +135,7 @@ def test_tail_bit_identity_staircase_engine(pa_graph, mode, extra):
     )
     st = init_swarm(pa_graph, cfg, origins=[0, 3], key=jax.random.key(8))
     outs = _run_tails(st, cfg, plan)
-    for tail in ("reference", "pallas"):
-        _assert_identical(outs["fused"][0], outs[tail][0], f"pallas/{tail}")
+    _assert_identical(outs["fused"][0], outs["reference"][0], "pallas/reference")
 
 
 @pytest.mark.parametrize("mode,extra", PLAN_ENGINE_GRID, ids=MODE_IDS)
@@ -153,8 +149,9 @@ def test_tail_bit_identity_matching_engine(matching, mode, extra):
         key=jax.random.key(9),
     )
     outs = _run_tails(st, cfg, plan)
-    for tail in ("reference", "pallas"):
-        _assert_identical(outs["fused"][0], outs[tail][0], f"matching/{tail}")
+    _assert_identical(
+        outs["fused"][0], outs["reference"][0], "matching/reference"
+    )
 
 
 @pytest.mark.slow  # loop-composed variant; the per-engine MODE_GRID
@@ -171,10 +168,10 @@ def test_tail_variants_identical_through_jitted_loops(pa_graph):
     st = init_swarm(pa_graph, cfg, origins=[0], key=jax.random.key(4))
     fins = {
         tail: simulate(clone_state(st), cfg, 8, None, tail)[0]
-        for tail in ("fused", "reference", "pallas")
+        for tail in ("fused", "reference", "packed")
     }
     _assert_identical(fins["fused"], fins["reference"], "simulate")
-    _assert_identical(fins["fused"], fins["pallas"], "simulate")
+    _assert_identical(fins["fused"], fins["packed"], "simulate")
     rounds = {
         tail: int(run_until_coverage(
             clone_state(st), cfg, 0.9, 60, tail=tail
@@ -182,6 +179,24 @@ def test_tail_variants_identical_through_jitted_loops(pa_graph):
         for tail in ("fused", "reference")
     }
     assert rounds["fused"] == rounds["reference"]
+
+
+@pytest.mark.parametrize("impl", ["pallas", "packed_pallas"])
+def test_removed_tail_is_refused(impl):
+    """A tail name outside TAIL_IMPLS is refused by the dispatcher, and
+    run_sim's parser refuses it as --tail."""
+    from tpu_gossip.cli.run_sim import build_parser
+    from tpu_gossip.kernels.round_tail import round_tail
+
+    b = jax.numpy.zeros((4, 2), bool)
+    with pytest.raises(ValueError, match="unknown tail impl"):
+        round_tail(
+            b, b, jax.numpy.full((4, 2), -1, jax.numpy.int16), b, b, b, b,
+            None, jax.numpy.asarray(1, jax.numpy.int32),
+            forward_once=False, sir_recover_rounds=0, impl=impl,
+        )
+    with pytest.raises(SystemExit):
+        build_parser().parse_args(["--tail", impl])
 
 
 # ------------------------------------------------------------- donation ---
@@ -274,13 +289,14 @@ def test_init_swarm_leaves_own_their_buffers(pa_graph, matching, graph_kind,
 
 
 def test_bench_swarm_donation_safe(pa_graph):
-    """bench_swarm reps clone internally: the caller's state survives the
-    benchmark, and the legacy zero-arg runner is rejected loudly."""
+    """bench_swarm clones internally: the caller's state survives the
+    warm-up and the timed run, and the legacy zero-arg runner is rejected
+    loudly."""
     from tpu_gossip.sim import metrics as M
 
     cfg = SwarmConfig(n_peers=N, msg_slots=4, fanout=3, mode="push")
     st = init_swarm(pa_graph, cfg, origins=[0])
-    res, fin = M.bench_swarm(st, cfg, 0.9, 100, reps=2)
+    res, fin = M.bench_swarm(st, cfg, 0.9, 100)
     assert res.rounds > 0
     assert float(st.coverage(0)) > 0  # caller's state intact
     with pytest.raises(TypeError, match="run\\(state\\)"):

@@ -1,8 +1,9 @@
-"""AOT compiles of the main path's Pallas kernels for a described TPU v5e.
+"""AOT compiles of the main path for a described TPU v5e.
 
 Nothing runs: each test lowers one kernel at real width with
-``interpret=False`` and compiles it for a chip that is described, not
-attached, so what Mosaic refuses fails here at no chip time. The topology
+``interpret=False``, or one whole matching round at a small swarm, and
+compiles it for a chip that is described, not attached, so what Mosaic
+or XLA refuses fails here at no chip time. The topology
 is described inside a module fixture (only the worker that runs this file
 loads the TPU compiler) and the persistent compilation cache is off around
 the compiles (a chip executable written there cannot be read back here).
@@ -64,7 +65,7 @@ def _compile(fn, *args) -> str:
 
 def test_interpret_follows_the_target_device(topo):
     """A kernel targets the chip under a TPU default device and is
-    interpreted under a CPU one (the chip smoke's CPU oracle)."""
+    interpreted under a CPU one (a CPU oracle run)."""
     from tpu_gossip.kernels.backend import interpret_default
 
     with jax.default_device(topo.devices[0]):
@@ -144,30 +145,49 @@ def test_segment_sampled(sds):
     )
 
 
+def _on_chip(sds, tree):
+    """Every array leaf of ``tree`` as a placeholder on the described chip."""
+    return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+
+@pytest.mark.parametrize("mode, fanout, forward_once", [
+    pytest.param("push_pull", 1, False, id="push_pull"),
+    pytest.param("flood", None, True, id="flood"),
+])
+def test_matching_round(topo, sds, mode, fanout, forward_once):
+    """One whole matching round with the fused tail, as the benchmark's
+    push-pull and flood configurations run it (16 slots, fanout 1 or
+    flood with forward-once), at a small swarm: the state and the plan
+    arrive as arguments placed on the described chip, which is also the
+    default device while the round is traced, so its kernels target
+    Mosaic."""
+    from tpu_gossip import SwarmConfig, init_swarm, matching_powerlaw_graph
+    from tpu_gossip.sim.engine import gossip_round
+
+    n = 4096
+    graph, plan = matching_powerlaw_graph(
+        n, gamma=2.5, fanout=fanout, key=jax.random.key(0)
+    )
+    padded = graph.as_padded_graph()
+    cfg = SwarmConfig(n_peers=padded.n, msg_slots=M, fanout=1, mode=mode,
+                      forward_once=forward_once)
+    state = init_swarm(padded, cfg, origins=[0], exists=graph.exists)
+    with jax.default_device(topo.devices[0]):
+        text = _compile(
+            lambda st, pl: gossip_round(st, cfg, pl, tail="fused"),
+            _on_chip(sds, state), _on_chip(sds, plan),
+        )
+    assert "%lane_shuffle" in text
+
+
 # (forward_once, sir_recover_rounds, churn fresh rows, stream expiry):
-# the headline round, and every optional operand of the kernel at once
+# the headline round, and every optional operand of the tail at once
 TAIL_CASES = [(False, 0, False, False), (True, 5, True, True)]
 
 
 @pytest.mark.parametrize("fo, sir, fresh, expired", TAIL_CASES)
-def test_tail_pallas(sds, fo, sir, fresh, expired):
-    from tpu_gossip.kernels.round_tail import tail_pallas
-
-    b = sds((N, M), jnp.bool_)
-
-    def f(seen, fwd, ir, rec, inc, recp, tx, rnd, fr, ex):
-        return tail_pallas(
-            seen, fwd, ir, rec, inc, recp, tx, fr, rnd, forward_once=fo,
-            sir_recover_rounds=sir, expired=ex, interpret=False,
-        )
-
-    _compile(f, b, b, sds((N, M), jnp.int16), b, b, b, b,
-             sds((), jnp.int32), sds((N,), jnp.bool_) if fresh else None,
-             sds((M,), jnp.bool_) if expired else None)
-
-
-@pytest.mark.parametrize("fo, sir, fresh, expired", TAIL_CASES)
 def test_round_tail_words(sds, fo, sir, fresh, expired):
+    """The packed engine's XLA word tail at 1M rows."""
     from tpu_gossip.kernels.round_tail import round_tail_words
 
     w = sds((N, W), jnp.uint8)
@@ -175,9 +195,11 @@ def test_round_tail_words(sds, fo, sir, fresh, expired):
     def f(seen, fwd, ir, rec, inc, recp, tx, rnd, fr, ex):
         return round_tail_words(
             seen, fwd, ir, rec, inc, recp, tx, fr, rnd, m=M, forward_once=fo,
-            sir_recover_rounds=sir, expired=ex, pallas=True, interpret=False,
+            sir_recover_rounds=sir, expired=ex,
         )
 
-    _compile(f, w, w, sds((N, M), jnp.int16), w, w, w, w,
-             sds((), jnp.int32), sds((N,), jnp.bool_) if fresh else None,
-             sds((M,), jnp.bool_) if expired else None)
+    jax.jit(f).lower(
+        w, w, sds((N, M), jnp.int16), w, w, w, w, sds((), jnp.int32),
+        sds((N,), jnp.bool_) if fresh else None,
+        sds((M,), jnp.bool_) if expired else None,
+    ).compile()

@@ -68,3 +68,15 @@ def test_bare_cli_parsers_accept_missing_port():
 
     assert peer_parser().parse_args([]).port is None
     assert seed_parser().parse_args([]).port is None
+
+
+@pytest.mark.parametrize("cell", ["pushpull_10m", "flood_1m", "pushpull_1m"])
+def test_benchmark_cell_parses(cell):
+    """Every benchmark cell's run_sim arguments still parse, pick the local
+    engine and run the fused tail: the seam between run_sim's parser and
+    the benchmark harness."""
+    from benchmark import harness, spec
+
+    args, engine = harness.sim_args(spec.load_cell(cell), 0)
+    assert engine.__name__ == "benchmark.engines.local"
+    assert args.tail == "fused"

@@ -1,4 +1,4 @@
-"""The one compile-cache helper run_sim, bench.py and chip_smoke.py share."""
+"""The one compile-cache helper that run_sim and the benchmark share."""
 
 import os
 

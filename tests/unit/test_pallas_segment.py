@@ -1,6 +1,6 @@
 """Staircase Pallas segment-OR: bit-exact parity with the XLA flood path
 (interpret mode on the CPU test backend; the same kernel runs compiled on
-TPU — see bench accounting)."""
+TPU), and the tile padding that buckets plan shapes."""
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +12,7 @@ from tpu_gossip.core.state import clone_state
 from tpu_gossip.core.topology import build_csr, configuration_model, powerlaw_degree_sequence, preferential_attachment
 from tpu_gossip.kernels.gossip import flood_all
 from tpu_gossip.kernels.pallas_segment import (
+    _pad_tiles,
     build_staircase_plan,
     build_staircase_plan_device,
     pack_words,
@@ -450,3 +451,25 @@ def test_engine_flood_with_plan_matches_without():
     fin_b, stats_b = simulate(st, cfg, 6, plan)
     assert bool(jnp.array_equal(fin_a.seen, fin_b.seen))
     np.testing.assert_array_equal(np.asarray(stats_a.coverage), np.asarray(stats_b.coverage))
+
+
+def test_pad_tiles_properties():
+    for t in [1, 2, 63, 64, 65, 127, 128, 129, 1000, 8191, 8192, 8193, 59904,
+              123456]:
+        p = _pad_tiles(t)
+        b = max(1, 1 << max(0, t.bit_length() - 7))
+        assert p >= t
+        assert p % b == 0
+        assert p - t < b  # minimal rounding
+        # worst-case inert-padding overhead bound documented in the docstring
+        assert (p - t) / t <= 1 / 64 + 1e-9 or t < 128
+
+
+def test_pad_tiles_buckets_similar_sizes_together():
+    # graphs of the same configuration differ by a handful of tiles across
+    # seeds; a ±100-tile spread crosses at most one 512-tile bucket
+    # boundary (usually none — one compile for the whole family)
+    base = 59904
+    buckets = {_pad_tiles(base + d) for d in range(-100, 101)}
+    assert len(buckets) <= 2
+    assert len({_pad_tiles(base - d) for d in range(100)}) == 1

@@ -4,7 +4,7 @@ Exit codes: 0 = clean (no findings beyond the baseline), 1 = new findings,
 2 = usage error. ``--fail-on-new`` is the default semantics and accepted
 explicitly for CI-invocation clarity.
 
-Default scope is the package + ``bench.py`` (tests are exempt — they
+Default scope is the package (tests are exempt — they
 deliberately construct pathological inputs); passing explicit paths lints
 just those files and SKIPS the contract audit (fixture linting must not
 import the fixtures' runtime). The contract audit needs a multi-device
@@ -33,7 +33,7 @@ from tpu_gossip.analysis.walker import ModuleInfo, Project
 
 __all__ = ["main", "lint_paths", "modules_for", "repo_root", "run_repo_lint"]
 
-_DEFAULT_SCOPE = ("tpu_gossip", "bench.py")
+_DEFAULT_SCOPE = ("tpu_gossip",)
 _EXCLUDE_PARTS = ("tests", ".git", "__pycache__", ".jax_cache")
 
 
@@ -109,14 +109,14 @@ def lint_paths(
 def run_repo_lint(
     with_contracts: bool = False, with_deep: bool = False
 ) -> dict:
-    """Programmatic entry (bench.py's lint_clean field): returns
+    """Programmatic entry: returns
     ``{"clean": bool, "new": [...], "baselined": n}`` over the default
     scope + baseline. ``with_deep`` adds the jaxpr deep tier IN-PROCESS
     (``deep_seconds`` records its wall time; the entry-point traces are
     shared with the contract audit through one per-invocation cache) —
     note it forces an 8-device XLA_FLAGS if none is set, so callers that
-    must keep their own device layout (bench.py) run the CLI in a
-    subprocess instead."""
+    must keep their own device layout run the CLI in a subprocess
+    instead."""
     root = repo_root()
     findings = lint_paths(list(_DEFAULT_SCOPE), root=root)
     out: dict = {}
@@ -211,8 +211,8 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument(
         "paths", nargs="*",
-        help="files/directories to lint (default: tpu_gossip/ bench.py + "
-        "contract audit)",
+        help="files/directories to lint (default: tpu_gossip/ + contract "
+        "audit)",
     )
     ap.add_argument(
         "--format", choices=("text", "json"), default="text",

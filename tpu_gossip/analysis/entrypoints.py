@@ -381,7 +381,7 @@ def _local_entries() -> list[EntryPoint]:
     # tail that drops, reshapes, or re-types a slot array cannot reach a
     # scan/while_loop carry without failing here first. Churn + SIR ride
     # along so the fresh-mask and recovery branches are traced too.
-    for tail in ("reference", "fused", "pallas", "packed", "packed_pallas"):
+    for tail in ("reference", "fused", "packed"):
         eps.append(round_ep(
             f"local[xla,tail={tail}]", "xla", ctx["dg"], 16, None,
             dict(mode="push_pull", sir_recover_rounds=4, **churn),

@@ -49,8 +49,7 @@ def run_mem(
     ``check_budget=False`` skips the budget gate (the --write-budget
     path prices entries without judging them).
 
-    The report (also the CLI's ``mem`` json block and bench.py's
-    ``mem_audit`` source) carries per-entry ledgers, the wire
+    The report (also the CLI's ``mem`` json block) carries per-entry ledgers, the wire
     cross-check, stale budget lines, and the registry-derived bytes/peer
     at 1M — the ROADMAP metric, computed from declared widths alone.
     """

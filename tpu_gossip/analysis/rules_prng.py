@@ -52,7 +52,7 @@ from tpu_gossip.analysis.walker import ModuleInfo
 __all__ = ["check_key_linearity"]
 
 # bare `rng` is deliberately NOT assumed to be a jax key: this codebase
-# threads numpy Generators under that name (cli/run_sim.py, bench.py,
+# threads numpy Generators under that name (cli/run_sim.py,
 # core/topology.py), and those are stateful — reuse is their contract.
 # Anything ASSIGNED from jax.random.* is tracked regardless of its name.
 _KEY_PARAM_RE = re.compile(r"^(key|k_\w+|key_\w+|\w+_key)$")

@@ -141,12 +141,11 @@ def build_parser() -> argparse.ArgumentParser:
         "(with --coordinator)",
     )
     p.add_argument(
-        "--tail", choices=["fused", "reference", "pallas"], default="fused",
+        "--tail", choices=["fused", "reference"], default="fused",
         help="protocol-tail implementation (kernels/round_tail.py): fused "
         "(single lax traversal, the default), reference (the historical "
-        "multi-pass sequence — the bitwise oracle), pallas (one kernel "
-        "launch; interpret-mode on CPU). All three are bit-identical; "
-        "local engine only",
+        "multi-pass sequence — the bitwise oracle). Both are "
+        "bit-identical; local engine only",
     )
     p.add_argument(
         "--pipeline", type=int, choices=[0, 1], default=None, metavar="DEPTH",
@@ -359,9 +358,8 @@ def build_parser() -> argparse.ArgumentParser:
         help="add state_digest (sha256 over the final state) to the "
         "summary, and on a fixed horizon stats_digest (over the integer "
         "stat trajectory) — the fields the recovery-smoke CI compares "
-        "between a SIGKILLed-then-resumed run and an uninterrupted one, "
-        "and chip_smoke.py between tails and platforms. Implied by "
-        "--checkpoint-every and by resume",
+        "between a SIGKILLed-then-resumed run and an uninterrupted one. "
+        "Implied by --checkpoint-every and by resume",
     )
     p.add_argument(
         "--profile", type=str, default="",
@@ -2494,8 +2492,7 @@ def _main_shard_matching(args, rng, spec=None, resume=None,
             # the timed region runs WITHOUT the analytic counter so the
             # sparse-vs-dense ms_per_round A/B measures pure transport;
             # the trajectory comes from an untimed bit-identical replay
-            # at the realized horizon (the bench.py pattern), summed in
-            # int64 host-side
+            # at the realized horizon, summed in int64 host-side
             def cov_run(st):
                 out = run_until_coverage_dist(
                     pack_state(st) if args.packed else st,

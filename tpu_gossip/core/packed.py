@@ -25,8 +25,8 @@ verbatim. :func:`pack_state`/:func:`unpack_state` are EXACT inverses
 accept a PackedSwarm and run the round NATIVELY on the words
 (``sim/packed_engine.py``, ``kernels/packed_ops.py``): delivery and
 dedup are word OR/AND/ANDN, infection counts are popcounts, the round
-tail has ``packed``/``packed_pallas`` implementations in the same
-bit-identity harness as the full-width tails, and the transport ships
+tail has a ``packed`` implementation in the same bit-identity harness
+as the full-width tails, and the transport ships
 the words themselves. Where a stage genuinely needs full width (the
 ``infected_round`` int16 latch, the fault head under an active
 scenario, the pipelined/rewire paths) it decodes exactly that plane for

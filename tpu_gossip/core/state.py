@@ -256,7 +256,7 @@ def state_bytes_per_peer(
     """The ROADMAP's tracked metric: declared state bytes per peer slot.
 
     Pure registry arithmetic — no arrays are built, so it is quotable at
-    any n (bench.py records it at 1M; the 100M item budgets against it).
+    any n (the 100M item budgets against it).
     With ``lanes`` > 1 the denominator is the AGGREGATE peer-slot count
     ``lanes * n`` — a batched campaign's bytes/peer equals the solo
     figure (stacking adds no per-peer overhead; only the per-lane

@@ -44,7 +44,7 @@ growth included (tests/sim/test_sparse_transport.py pins the full matrix).
 The analytic counters (:func:`ici_round_bucketed`,
 :func:`ici_round_matching`) model the fault-free single-pass exchange of
 each round from the transmit plane alone, so the bytes-on-the-wire metric
-is tracked even on CPU-only containers (bench.py ``ici_bytes_per_round``).
+is tracked even on CPU-only hosts.
 """
 
 from __future__ import annotations

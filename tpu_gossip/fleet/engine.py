@@ -7,8 +7,7 @@ fixed horizon. Every lane runs the full composed protocol — chaos
 scenario, growth admission, streaming injection, adaptive control —
 against its own stacked plan tables, and ONE compile serves all K lanes:
 the lane axis is just one more array dimension to XLA, so the per-op
-dispatch overhead that K serial processes pay K times is paid once
-(bench.py ``fleet_1m`` records the realized swarms/sec win).
+dispatch overhead that K serial processes pay K times is paid once.
 
 The conformance contract (tests/sim/test_fleet.py): lane k of the
 batched run is BIT-IDENTICAL — full state plus every integer stat — to a

@@ -43,7 +43,7 @@ every lane must share every jit-static property: same n, m, horizon,
 ``max_inject``, fanout-table width. The sampled axes are exactly the
 ones that ride TRACED leaves — fault-phase parameters (per-phase table
 values), traffic rates (a traced scalar; ``max_inject`` is pinned to the
-largest sampled rate, the bench.py saturation-curve pattern), and
+largest sampled rate, so one compile serves every rate), and
 controller bounds (per-lane CLAMPED fanout tables over one global-width
 spec). An axis that would move a static shape — peers, slots, rounds,
 TTL, Bloom width — is rejected at parse time (exit 2 from the CLI),
@@ -831,8 +831,8 @@ def compile_campaign(spec: CampaignSpec):
             np.flatnonzero(np.asarray(exists)) if exists is not None
             else np.arange(n_peers)
         )
-        # ONE static batch shape serves every sampled rate (the bench.py
-        # saturation-curve pattern): max_inject pins to the largest lane
+        # ONE static batch shape serves every sampled rate: max_inject
+        # pins to the largest lane
         peak = max(lane_rates)
         try:
             shared_inject = default_max_inject(peak)

@@ -13,7 +13,7 @@ from tpu_gossip.kernels.liveness import emit_heartbeats, detect_failures
 
 # NOTE: tpu_gossip.kernels.pallas_segment (StaircasePlan, plan builders,
 # segment_or/segment_sampled) is deliberately NOT re-exported here — every
-# consumer (sim/engine.py, cli/run_sim.py, bench.py) imports it lazily so
+# consumer (sim/engine.py, cli/run_sim.py) imports it lazily so
 # the jax.experimental.pallas/.tpu stack loads only when a plan is used,
 # and pure-XLA runs work even where that import can't.
 __all__ = [

@@ -2,8 +2,8 @@
 
 A kernel compiles through Mosaic for the chip, or runs in interpret mode
 when the computation targets the CPU: the process's default backend, or a
-``jax.default_device`` scope naming a CPU device (the chip smoke's CPU
-oracle runs in the same process as its TPU run). jit keys its trace on
+``jax.default_device`` scope naming a CPU device (a CPU oracle can run
+in the same process as a TPU run). jit keys its trace on
 the default device, so each target gets its own trace.
 """
 

@@ -1,6 +1,7 @@
 """Structured-permutation passes: the gather-free data movement primitive.
 
-Measured reality on this chip (experiments/gather_probe.py, 2026-07-30):
+Measured reality on this chip (2026-07-30; the probe is
+``git show 2e81452:experiments/gather_probe.py``):
 EVERY XLA-level gather shape — flat random, wide-slice two-step, tall
 ``take_along_axis``, even a pure in-register lane shuffle — runs at the same
 ~126M elements/s, because XLA:TPU lowers them all through one serialized
@@ -8,7 +9,8 @@ gather path. That rate is what bounds the staircase kernel's feed (40 ms of
 a ~50 ms round at 1M peers, docs/kernel_profile_1m.md). Mosaic, by contrast,
 compiles ``take_along_axis`` to the hardware's vreg-local ``dynamic_gather``:
 strictly 8-wide on sublanes and 128-wide on lanes — useless as a general
-gather, but running at ~188 G elements/s (experiments/perm_pipeline_probe.py).
+gather, but running at ~188 G elements/s
+(``git show 5b4b0c9:experiments/perm_pipeline_probe.py``).
 
 This module turns that one fast primitive into bulk data movement: a
 *structured permutation* is a composition of

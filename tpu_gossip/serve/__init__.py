@@ -27,7 +27,7 @@ real clients:
   + integer-stat trajectory — the project's bit-identity discipline
   extended across the socket boundary).
 - ``serve/loadgen.py`` — the scripted multi-client load generator the
-  CI smoke job and ``bench.py serve_1m`` drive the frontend with.
+  CI smoke job drives the frontend with.
 """
 
 from tpu_gossip.serve.driver import (

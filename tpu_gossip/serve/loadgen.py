@@ -3,8 +3,7 @@
 Real sockets, real threads — one thread per simulated reference client,
 each writing the reference's gossip wire lines
 (``compat.wire.encode_gossip``) with optional jittered pacing. The CI
-``serve-smoke`` job and ``bench.py serve_1m`` drive the frontend with
-this; the trace-replay golden test uses it for its live leg.
+``serve-smoke`` job drives the frontend with this; the trace-replay golden test uses it for its live leg.
 
 Determinism note: the PAYLOADS are deterministic given (clients, msgs,
 seed) — what round each lands in is real wall-clock racing, which is

@@ -862,13 +862,11 @@ def advance_round(
     Structured as row-level work first (liveness counters, churn draws —
     O(N)), then ONE fused traversal of the (N, M) slot arrays
     (``kernels.round_tail``) producing seen/forwarded/infected_round/
-    recovered together: the post-delivery passes that dominated the 1M
-    round (~10× the delivery stage, VERDICT r5 item 7) read each operand
-    once instead of once per pass. ``tail`` selects the implementation
-    ("fused" lax chain, "reference" historical pass sequence, "pallas"
-    single-kernel launch) — all three are bit-identical (integer ops
-    only), so any choice preserves the local↔sharded bit-identity
-    contract.
+    recovered together: the post-delivery passes read each operand once
+    instead of once per pass. ``tail`` selects the implementation
+    ("fused" lax chain, "reference" historical pass sequence, "packed"
+    word-tail adapter) — all three are bit-identical (integer ops only),
+    so any choice preserves the local↔sharded bit-identity contract.
 
     ``faults`` (a :class:`~tpu_gossip.faults.inject.RoundFaults`) carries
     an active scenario's per-round parameters: blacked-out nodes read as
@@ -1019,8 +1017,8 @@ def gossip_round(
 
     ``tail`` selects the protocol-tail implementation (see
     ``kernels.round_tail``): "fused" (default), "reference" (the historical
-    multi-pass oracle), "pallas" (one kernel launch) — bit-identical all
-    three.
+    multi-pass oracle), "packed" (the word tail behind a bool adapter) —
+    bit-identical all three.
 
     ``scenario`` (a :class:`~tpu_gossip.faults.CompiledScenario`) injects
     that round's faults: the protocol's 5-way key split is untouched and

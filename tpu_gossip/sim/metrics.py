@@ -72,8 +72,6 @@ def bench_swarm(
     target: float = 0.99,
     max_rounds: int = 1000,
     *,
-    warmup: bool = True,
-    reps: int = 1,
     plan=None,
     run=None,
     n_peers: int | None = None,
@@ -81,26 +79,27 @@ def bench_swarm(
 ) -> tuple[BenchResult, SwarmState]:
     """Time the run-to-coverage while_loop on device (compile excluded).
 
-    Returns ``(best_result, final_state)`` — the min-wall measurement over
-    ``reps`` repetitions and the actual final state, so callers can checkpoint what was
-    measured.
+    Returns ``(result, final_state)`` — one timed run after one untimed
+    warm-up run, and the timed run's final state, so callers can
+    checkpoint what was measured.
 
-    The round entry points DONATE their state (sim/engine.py), so every
-    repetition runs on a fresh ``clone_state`` of ``state``, cloned BEFORE
-    the timer starts — the measured region is the pure donated run, with no
-    hidden input copy, and the caller's ``state`` survives the benchmark.
+    The round entry points DONATE their state (sim/engine.py), so the
+    warm-up and the timed run each run on a fresh ``clone_state`` of
+    ``state``, cloned BEFORE the timer starts — the measured region is
+    the pure donated run, with no hidden input copy, and the caller's
+    ``state`` survives.
 
     ``run`` swaps in a different run-to-coverage callable (the sharded
     engine's ``run_until_coverage_dist``, a custom horizon) while keeping
-    THIS timing harness — warmup, per-rep clone, scalar-fetch completion
-    barrier, min-over-reps — in exactly one place. It must accept the
-    (already-cloned, donatable) state as its ONE argument and return the
-    final state; a zero-arg callable (the pre-donation API) is rejected
-    loudly — it would close over a state the first call deletes.
+    THIS timing harness — warm-up, clone, scalar-fetch completion
+    barrier — in exactly one place. It must accept the (already-cloned,
+    donatable) state as its ONE argument and return the final state; a
+    zero-arg callable (the pre-donation API) is rejected loudly — it
+    would close over a state the first call deletes.
     ``n_peers`` overrides the reported swarm size (e.g. the real peer count
     when ``cfg.n_peers`` is a padded slot count). ``tail`` selects the
-    protocol-tail implementation for the default runner (A/B hook for
-    kernels/round_tail.py; ignored with a custom ``run``).
+    protocol-tail implementation for the default runner (ignored with a
+    custom ``run``).
     """
     from tpu_gossip.core.state import clone_state
 
@@ -118,37 +117,31 @@ def bench_swarm(
                 "bench_swarm: run= must accept the state to run on "
                 "(run(state) -> final_state) — the engines donate their "
                 "state, so a zero-arg runner would re-donate a deleted "
-                "closure state on the second repetition"
+                "closure state on the timed run"
             )
     else:
         run = lambda st: run_until_coverage(  # noqa: E731
             st, cfg, target, max_rounds, plan=plan, tail=tail)
     n = cfg.n_peers if n_peers is None else n_peers
-    if warmup:
-        float(run(clone_state(state)).coverage(0))
-    best = None
-    fin = state
-    for _ in range(max(reps, 1)):
-        rep_state = clone_state(state)  # outside the timed region
-        t0 = time.perf_counter()
-        fin = run(rep_state)
-        # host-fetch a scalar inside the timed region: it is read off the
-        # loop's final state, so the timer stops when the device is done
-        coverage = float(fin.coverage(0))
-        rounds = int(fin.round - state.round)
-        dt = time.perf_counter() - t0
-        res = BenchResult(
-            n_peers=n,
-            rounds=rounds,
-            target=target,
-            wall_seconds=dt,
-            peers_rounds_per_sec=n * rounds / max(dt, 1e-9),
-            coverage=coverage,
-            ms_per_round=dt / max(rounds, 1) * 1000.0,
-        )
-        if best is None or res.wall_seconds < best.wall_seconds:
-            best = res
-    return best, fin
+    float(run(clone_state(state)).coverage(0))  # untimed warm-up: compiles
+    run_state = clone_state(state)  # outside the timed region
+    t0 = time.perf_counter()
+    fin = run(run_state)
+    # host-fetch a scalar inside the timed region: it is read off the
+    # loop's final state, so the timer stops when the device is done
+    coverage = float(fin.coverage(0))
+    rounds = int(fin.round - state.round)
+    dt = time.perf_counter() - t0
+    result = BenchResult(
+        n_peers=n,
+        rounds=rounds,
+        target=target,
+        wall_seconds=dt,
+        peers_rounds_per_sec=n * rounds / max(dt, 1e-9),
+        coverage=coverage,
+        ms_per_round=dt / max(rounds, 1) * 1000.0,
+    )
+    return result, fin
 
 
 def stats_rows(stats: RoundStats) -> Iterable[dict]:
@@ -392,8 +385,7 @@ def reliability_report(
     it paid — **messages per delivered infection** (total protocol sends
     over every (peer, slot) first-receipt the horizon realized) and the
     p50/p99 **rounds-to-coverage**. Evaluated over the whole
-    ``scenarios/`` catalogue by tests/sim/test_control.py, and recorded
-    at 1M by ``bench.py control_1m``.
+    ``scenarios/`` catalogue by tests/sim/test_control.py.
 
     Streaming runs (the per-slot tracks carry data) judge per MESSAGE:
     an episode whose lease closed inside the horizon either covered to

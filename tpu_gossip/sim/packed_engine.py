@@ -208,10 +208,9 @@ def _stream_ageout_stage_packed(stream):
 
 def _tail_stage_packed(cfg, tail: str, m: int):
     """Word twin of ``sim.stages._tail_stage``: one traversal of the
-    (N, W) word planes (``kernels.round_tail.round_tail_words``). The
-    bool impl names map onto the two packed impls (``pallas`` /
-    ``packed_pallas`` → the Pallas word-block kernel, everything else →
-    the XLA word chain) so ``--packed --tail fused`` keeps working."""
+    (N, W) word planes (``kernels.round_tail.round_tail_words``), the
+    one word tail whatever bool impl ``tail`` names, so ``--packed --tail
+    fused`` keeps working."""
     from tpu_gossip.sim.stages import Stage
 
     reads = (
@@ -231,7 +230,6 @@ def _tail_stage_packed(cfg, tail: str, m: int):
             forward_once=cfg.forward_once,
             sir_recover_rounds=cfg.sir_recover_rounds,
             expired=ctx["expired"],
-            pallas=tail in ("pallas", "packed_pallas"),
         )
         return {
             "seen": seen, "forwarded": forwarded,

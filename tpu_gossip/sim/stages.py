@@ -551,7 +551,7 @@ def _tail_stage(cfg, tail: str) -> Stage:
     """ONE fused traversal of the (N, M) slot arrays
     (``kernels.round_tail``): dedup merge + infection latch + per-slot SIR
     + churn fresh resets + stream expiry resets, each output materialized
-    once. ``tail`` selects the implementation (fused/reference/pallas) —
+    once. ``tail`` selects the implementation (fused/reference/packed) —
     bit-identical all three."""
     reads = (
         "seen", "forwarded", "infected_round", "recovered", "incoming",

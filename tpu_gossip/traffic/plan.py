@@ -88,8 +88,8 @@ class CompiledStream:
 def default_max_inject(peak_rate: float) -> int:
     """The static per-round arrival batch a peak Poisson rate needs: the
     +6σ tail makes a dropped arrival a <1e-8 event per round. Callers
-    pinning one compile across several rates (bench.py's saturation
-    curve) pass their LARGEST rate here."""
+    pinning one compile across several rates (a fleet campaign's rate
+    axis) pass their LARGEST rate here."""
     return max(
         int(math.ceil(peak_rate + 6.0 * math.sqrt(max(peak_rate, 1.0)))), 4
     )

@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, in one place for every entry point.
 
-``run_sim``, ``bench.py`` and ``chip_smoke.py`` call :func:`use_compile_cache`
-before their first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+``run_sim`` and the benchmark (``benchmark/run.py``) call
+:func:`use_compile_cache` before their first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
 already reads it and nothing is set here. Otherwise the cache lives at the
 fixed ``<repo>/.jax_cache`` (git-ignored): the directory is part of the
 cache key, so a temporary or per-process path would never hit.
